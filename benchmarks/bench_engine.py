@@ -8,11 +8,7 @@ cycles/sec) so the performance trajectory is recorded run over run.
 """
 from __future__ import annotations
 
-import argparse
 import json
-import os
-import subprocess
-import sys
 import time
 
 
@@ -260,27 +256,14 @@ def run(report, n_cycles: int = 20_000, json_path: str = "BENCH_engine.json"):
     results["fast_forward_speedup_floor"] = round(0.5 * ff_speedup, 3)
 
     # scale-out: the channel-sharded engine (shard_map over the channel
-    # mesh) and the device-sharded sweep, at forced host device counts
-    # {1, 4}.  XLA fixes the device count at backend init, so each
-    # measurement is a subprocess that pins XLA_FLAGS before importing
-    # jax (this file's --scale-probe entry point).  The ratios compare
-    # the SAME workloads across the two device counts on the same box.
-    probe = {}
-    here = os.path.abspath(__file__)
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    src = os.path.join(os.path.dirname(os.path.dirname(here)), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    for ndev in (1, 4):
-        r = subprocess.run(
-            [sys.executable, here, "--scale-probe", "--devices", str(ndev),
-             "--cycles", str(n_cycles), "--points", "64"],
-            env=env, capture_output=True, text=True, timeout=1800)
-        if r.returncode != 0:
-            raise RuntimeError(f"scale probe (devices={ndev}) failed:\n"
-                               + r.stderr[-2000:])
-        probe[ndev] = json.loads(r.stdout.strip().splitlines()[-1])
-    ch1, ch4 = probe[1]["channel"], probe[4]["channel"]
-    sw1, sw4 = probe[1]["sweep"], probe[4]["sweep"]
+    # mesh) and the device-sharded sweep, each against its one-device
+    # twin in this same process (a chip belongs to one process, so no
+    # child may take the devices).  The "4" side uses every visible
+    # device; run with XLA_FLAGS=--xla_force_host_platform_device_count=4
+    # on a CPU box to give it four.
+    probe = _scale_probe(n_cycles, 64)
+    ch1, ch4 = probe["channel"]["1"], probe["channel"]["4"]
+    sw1, sw4 = probe["sweep"]["1"], probe["sweep"]["4"]
     ch_speedup = (ch4["aggregate_channel_cycles_per_sec"]
                   / max(ch1["aggregate_channel_cycles_per_sec"], 1))
     sw_speedup = sw1["wall_s"] / max(sw4["wall_s"], 1e-9)
@@ -293,7 +276,7 @@ def run(report, n_cycles: int = 20_000, json_path: str = "BENCH_engine.json"):
            f"4ch scalar engine, shard_map d={ch4['shard']} vs "
            f"single-device vmap ({ch4['wall_s']}s vs {ch1['wall_s']}s)")
     report("sweep_scaling_speedup_1_to_4", round(sw_speedup, 2),
-           f"{sw1['points']}-point sweep, 4 forced host devices vs 1 "
+           f"{sw1['points']}-point sweep, {sw4['devices']} devices vs 1 "
            f"({sw4['wall_s']}s vs {sw1['wall_s']}s)")
     # merge-time floors for the CI gate: forced host devices on a small
     # runner time-slice one physical core rather than parallelize, so the
@@ -324,38 +307,34 @@ def run(report, n_cycles: int = 20_000, json_path: str = "BENCH_engine.json"):
     report("bench_engine_json", json_path, "perf trajectory artifact")
 
 
-def _scale_probe(n_devices: int, n_cycles: int, n_points: int) -> dict:
-    """Runs in a subprocess (one per forced device count): measure the
-    channel-sharded scalar engine and the device-sharded streamed sweep
-    under exactly ``n_devices`` host devices.  Must only be called after
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` is pinned
-    (the ``--scale-probe`` entry point does)."""
+def _scale_probe(n_cycles: int, n_points: int) -> dict:
+    """Measure the 4-channel scalar engine with ``channel_shard=False``
+    (side "1") against auto channel sharding over the visible devices
+    (side "4"), and a ``n_points``-point sweep on the first device against
+    the sweep sharded over every device.  Each side is warmed first and
+    timed as the best of a few runs."""
     import jax
     from repro.core import Simulator
     from repro.core import engine as E
     from repro.core.frontend import FrontendConfig
     from repro.dse import SweepSpec, execute
 
-    assert jax.device_count() == n_devices, jax.device_count()
-    out = {"devices": n_devices}
-
-    # channel axis: a 4-channel scalar run.  With >1 device the channel
-    # axis auto-shards over the mesh (shard_map, d=4); with 1 device the
-    # same workload stays on the vmap path — the gate's aggregate-speedup
-    # ratio compares exactly these two placements.
-    sim = Simulator("DDR4", "DDR4_8Gb_x8", "DDR4_2400R", channels=4,
-                    frontend=FrontendConfig(probes=False))
-    shard = sim._resolved_shard()
-    jax.block_until_ready(sim.run(n_cycles))            # compile + warm
-    best = float("inf")
-    for _ in range(3):
-        t0 = time.perf_counter()
-        jax.block_until_ready(sim.run(n_cycles))
-        best = min(best, time.perf_counter() - t0)
-    out["channel"] = {
-        "channels": 4, "shard": int(shard) if shard else 0,
-        "wall_s": round(best, 4),
-        "aggregate_channel_cycles_per_sec": int(4 * n_cycles / best)}
+    out = {"channel": {}, "sweep": {}}
+    for side, cs in (("1", False), ("4", None)):
+        sim = Simulator("DDR4", "DDR4_8Gb_x8", "DDR4_2400R", channels=4,
+                        frontend=FrontendConfig(probes=False),
+                        channel_shard=cs)
+        shard = sim._resolved_shard()
+        jax.block_until_ready(sim.run(n_cycles))            # compile + warm
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            jax.block_until_ready(sim.run(n_cycles))
+            best = min(best, time.perf_counter() - t0)
+        out["channel"][side] = {
+            "channels": 4, "shard": int(shard) if shard else 0,
+            "wall_s": round(best, 4),
+            "aggregate_channel_cycles_per_sec": int(4 * n_cycles / best)}
 
     # sweep axis: one compile group, ``n_points`` load points sharded
     # across the device mesh with donated carries + streamed collection
@@ -364,30 +343,20 @@ def _scale_probe(n_devices: int, n_cycles: int, n_points: int) -> dict:
         intervals=tuple(1.0 + 0.5 * i for i in range(n_points // 4)),
         read_ratios=(1.0, 0.9, 0.8, 0.7),
         n_cycles=max(n_cycles // 5, 2_000))
-    cache = E.RunCache()
-    execute(spec, cache=cache)                          # compile + warm
-    best = float("inf")
-    for _ in range(2):
-        t0 = time.perf_counter()
-        execute(spec, cache=cache)
-        best = min(best, time.perf_counter() - t0)
-    out["sweep"] = {"points": spec.n_points, "wall_s": round(best, 4)}
+    for side, devices in (("1", jax.devices()[:1]), ("4", jax.devices())):
+        cache = E.RunCache()
+        execute(spec, cache=cache, devices=devices)         # compile + warm
+        best = float("inf")
+        for _ in range(2):
+            t0 = time.perf_counter()
+            execute(spec, cache=cache, devices=devices)
+            best = min(best, time.perf_counter() - t0)
+        out["sweep"][side] = {"points": spec.n_points,
+                              "devices": len(devices),
+                              "wall_s": round(best, 4)}
     return out
 
 
 if __name__ == "__main__":
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--scale-probe", action="store_true",
-                    help="subprocess mode: measure under a forced host "
-                         "device count and print one JSON line")
-    ap.add_argument("--devices", type=int, default=1)
-    ap.add_argument("--cycles", type=int, default=20_000)
-    ap.add_argument("--points", type=int, default=64)
-    a = ap.parse_args()
-    if a.scale_probe:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={a.devices}")
-        print(json.dumps(_scale_probe(a.devices, a.cycles, a.points)))
-    else:
-        run(lambda name, value, derived="":
-            print(f"{name},{value},{derived}", flush=True))
+    run(lambda name, value, derived="":
+        print(f"{name},{value},{derived}", flush=True))

@@ -50,4 +50,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.engine import enable_compile_cache
+    enable_compile_cache()
     main()

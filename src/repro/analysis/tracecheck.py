@@ -53,7 +53,6 @@ from repro.analysis.report import ERROR, WARN, Finding, LintReport
 #: jax.numpy — the compiled hot paths and their direct model/kernel
 #: dependencies.  Everything else is host-side by policy (TS105).
 JNP_ALLOWLIST = frozenset({
-    "repro.compat",
     "repro.core.controller", "repro.core.device", "repro.core.engine",
     "repro.core.frontend",
     "repro.data.pipeline",

@@ -17,6 +17,7 @@ programs.  This is the TPU-native analogue of Ramulator's DSE workflows
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
 from functools import partial
 from typing import NamedTuple
@@ -299,6 +300,14 @@ def auto_channel_shard(spec, n_devices: int | None = None) -> int | None:
     return None
 
 
+def _vary(tree, axis_name):
+    """Mark every leaf of ``tree`` as varying over the ``shard_map`` axis
+    ``axis_name`` (leaves that already vary are kept as they are)."""
+    return jax.tree.map(
+        lambda a: a if axis_name in jax.typeof(a).vma
+        else jax.lax.pcast(a, axis_name, to="varying"), tree)
+
+
 def _shard_desc(shard):
     """Hashable mesh identity of a channel-sharded program: axis name,
     mesh size, and the participating devices' (platform, id) pairs — a
@@ -458,6 +467,26 @@ class RunCache:
 
 #: Process-wide default cache used by `Simulator` and `repro.dse`.
 RUN_CACHE = RunCache()
+
+#: JAX's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR``
+#: is unset: a fixed directory of the checkout, since the cache only hits
+#: when the path is the same from one process to the next.
+COMPILE_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a program entry
+    point and return its directory.  ``JAX_COMPILATION_CACHE_DIR``, when
+    set, is JAX's own setting and is left alone; otherwise the cache is
+    kept in :data:`COMPILE_CACHE_DIR`.  Entry points call this as they
+    start; library code and tests never do."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 @dataclasses.dataclass
@@ -923,7 +952,12 @@ def make_run(spec, ccfg: C.ControllerConfig,
                 cs=css,
                 ch=_zero_channel_stats(cspec, bool(telemetry_window),
                                        n_channels=loc)))
-        init = SimState(gs=tuple(gs), fs=F.init_front(), clk=jnp.int32(0))
+        gs = tuple(gs)
+        if shard_index is not None:
+            # each shard owns its channel slice: the group state varies
+            # over the mesh axis from the first loop iteration on
+            gs = _vary(gs, CHANNEL_AXIS)
+        init = SimState(gs=gs, fs=F.init_front(), clk=jnp.int32(0))
         return init._replace(
             fs=init.fs._replace(rng=seed | jnp.uint32(1)))
 
@@ -1059,6 +1093,9 @@ def make_run(spec, ccfg: C.ControllerConfig,
         snaps0 = jax.tree.map(
             lambda s: jnp.zeros((n_full,) + s.shape, s.dtype),
             jax.eval_shape(snapshot, init)) if W else None
+        if axis_name is not None:
+            # per-shard buffers: their loop carry varies over the mesh
+            bufs0, snaps0 = _vary((bufs0, snaps0), axis_name)
 
         def cond(c):
             return c[0].clk < jnp.int32(n_cycles)
@@ -1076,7 +1113,13 @@ def make_run(spec, ccfg: C.ControllerConfig,
                         bufs[g], ys[g])
                     for g in range(n_groups))
             busy = (loc[0] + loc[1] + loc[5]) > 0
-            h = jax.lax.cond(busy, lambda _: out.clk,
+            nxt_clk = out.clk
+            if axis_name is not None:
+                # the horizon reads this shard's channels, so it varies
+                # over the mesh axis: both cond branches must carry the
+                # same varying axes, and the pmin makes h uniform again
+                nxt_clk = _vary(nxt_clk, axis_name)
+            h = jax.lax.cond(busy, lambda _: nxt_clk,
                              lambda _: _horizon(out, dps, fp), None)
             if axis_name is not None:
                 h = jax.lax.pmin(h, axis_name)
@@ -1156,8 +1199,6 @@ def make_run(spec, ccfg: C.ControllerConfig,
     # group's channel axis; out_specs gather the per-channel outputs
     # back onto the global channel axis, and the replicated aggregation
     # below is shared verbatim with the vmapped path.
-    from repro.compat import ensure_jax_shard_map_compat
-    ensure_jax_shard_map_compat()
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 

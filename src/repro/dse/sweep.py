@@ -109,4 +109,6 @@ def main(argv=None) -> SweepResult:
 
 
 if __name__ == "__main__":
+    from repro.core.engine import enable_compile_cache
+    enable_compile_cache()
     main()

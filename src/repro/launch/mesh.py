@@ -8,10 +8,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import ensure_jax_sharding_compat
-
-ensure_jax_sharding_compat()
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)

@@ -20,12 +20,6 @@ import logging
 import time
 from typing import Callable, Optional
 
-from repro.compat import ensure_jax_sharding_compat
-
-# elastic remesh callbacks build meshes with ``axis_types=`` — make that
-# API available on jax versions that predate it before any mesh exists
-ensure_jax_sharding_compat()
-
 log = logging.getLogger("repro.runtime")
 
 

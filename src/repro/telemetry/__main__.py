@@ -158,4 +158,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.core.engine import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -178,6 +178,5 @@ def test_hot_path_modules_lint_clean():
 
 def test_allowlist_names_only_real_modules():
     mods = load_modules([REPRO_DIR], root=SRC_ROOT)
-    missing = [m for m in JNP_ALLOWLIST if m != "repro.compat"
-               and m not in mods]
+    missing = [m for m in JNP_ALLOWLIST if m not in mods]
     assert not missing, missing
