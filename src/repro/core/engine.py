@@ -199,6 +199,44 @@ class TraceArrays(NamedTuple):
 #: assert that identical sweep specs are compiled exactly once.
 TRACE_COUNT = 0
 
+#: JAX's compile-stage events and the ``RunCache.stats()`` field each one
+#: feeds: durations in seconds, the persistent-cache events as counts.
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+    "/jax/compilation_cache/cache_hits": "persistent_hits",
+    "/jax/compilation_cache/cache_misses": "persistent_misses",
+}
+
+
+def _zero_stages() -> dict:
+    return {f: 0 if f.startswith("persistent_") else 0.0
+            for f in COMPILE_EVENTS.values()}
+
+
+#: process totals of :data:`COMPILE_EVENTS` since import; a
+#: :class:`RunCache` adds the part that falls inside its programs' first
+#: calls
+_COMPILE_TOTALS = _zero_stages()
+
+
+def _on_compile_duration(event: str, duration: float, **_) -> None:
+    field = COMPILE_EVENTS.get(event)
+    if field is not None:
+        _COMPILE_TOTALS[field] += duration
+
+
+def _on_compile_event(event: str, **_) -> None:
+    field = COMPILE_EVENTS.get(event)
+    if field is not None:
+        _COMPILE_TOTALS[field] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
+jax.monitoring.register_event_listener(_on_compile_event)
+
 
 def _freeze(obj):
     """Recursively convert configs/dicts into hashable cache-key tuples.
@@ -348,24 +386,31 @@ def run_key(spec, ccfg: C.ControllerConfig,
 
 class _TimedRun:
     """Callable wrapper around one cached jitted run: its FIRST call —
-    trace + XLA compile + the run itself, synchronized — is timed into the
-    owning cache's ``first_call_s``.  Warm calls pass straight through.
-    This is the observable the run profiler reports as compile cost (the
-    pure-execute share is separately measurable from a warm re-run)."""
+    trace + XLA compile + the run itself, synchronized — is timed, with
+    the compile stages JAX reports inside it (:data:`COMPILE_EVENTS`),
+    into one record of the owning cache's :meth:`RunCache.first_calls`.
+    Warm calls pass straight through.  This is the observable the run
+    profiler reports as compile cost (the pure-execute share is
+    separately measurable from a warm re-run)."""
 
-    __slots__ = ("fn", "_cache", "_timed")
+    __slots__ = ("fn", "_cache", "_timed", "_n_cycles")
 
-    def __init__(self, fn, cache: "RunCache"):
+    def __init__(self, fn, cache: "RunCache", n_cycles: int):
         self.fn = fn
         self._cache = cache
         self._timed = False
+        self._n_cycles = int(n_cycles)
 
     def __call__(self, *args):
         if self._timed:
             return self.fn(*args)
+        before = dict(_COMPILE_TOTALS)
         t0 = time.perf_counter()
         out = jax.block_until_ready(self.fn(*args))
-        self._cache.first_call_s += time.perf_counter() - t0
+        record = {"n_cycles": self._n_cycles,
+                  "first_call_s": time.perf_counter() - t0}
+        record.update({k: v - before[k] for k, v in _COMPILE_TOTALS.items()})
+        self._cache._first_calls.append(record)
         self._timed = True
         return out
 
@@ -377,17 +422,18 @@ class RunCache:
     over ``fp`` when ``batched=True``).  ``hits``/``misses`` count lookups;
     re-tracing is observable via the module-level ``TRACE_COUNT``, and
     ``stats()`` publishes the full accounting (entries, hit/miss counts,
-    cumulative first-call wall time) for the run profiler and the DSE
-    sweep reports.
+    cumulative first-call wall time and its compile stages) for the run
+    profiler and the DSE sweep reports; ``first_calls()`` gives the same
+    per program.
     """
 
     def __init__(self):
         self._runs: dict = {}
         self.hits = 0
         self.misses = 0
-        #: cumulative wall seconds of every cached program's FIRST call
-        #: (trace + XLA compile + one synchronized run)
-        self.first_call_s = 0.0
+        #: one record per cached program's FIRST call (trace + XLA
+        #: compile + one synchronized run), in the order they ended
+        self._first_calls: list = []
         #: distinct program topologies compiled ("vmap" single-device,
         #: "channels:<d>" for channel-sharded meshes)
         self._topologies: set = set()
@@ -398,21 +444,43 @@ class RunCache:
     def clear(self):
         self._runs.clear()
         self.hits = self.misses = 0
-        self.first_call_s = 0.0
+        self._first_calls.clear()
         self._topologies.clear()
+
+    def first_calls(self) -> list:
+        """One dict per cached program whose first call has ended, in that
+        order: its ``n_cycles``, the call's wall seconds ``first_call_s``
+        and the compile stages JAX reported inside it (the
+        :data:`COMPILE_EVENTS` fields of :meth:`stats`)."""
+        return [dict(r) for r in self._first_calls]
 
     def stats(self) -> dict:
         """Public cache accounting: ``entries`` (live programs), ``hits``
         / ``misses`` (lookup counts since construction/clear),
         ``first_call_s`` (cumulative wall time of each program's first
-        call — the trace + compile cost plus one run), plus the device
+        call — the trace + compile cost plus one run) and, inside those
+        first calls, the compile stages JAX reports: ``trace_s`` (jaxpr
+        tracing), ``lower_s`` (lowering to an MLIR module), ``compile_s``
+        (XLA compile, or the load from JAX's persistent compilation
+        cache), ``cache_load_s`` (that load alone), ``persistent_hits``
+        and ``persistent_misses`` (persistent-cache lookups that hit, and
+        misses whose program was written to it); plus the device
         topology view: ``devices`` (visible device count) and
         ``shard_topologies`` (distinct program topologies compiled —
         ``"vmap"`` for single-device programs, ``"channels:<d>"`` for
         channel-sharded meshes)."""
+        totals = _zero_stages()
+        first_call_s = 0.0
+        for r in self._first_calls:
+            first_call_s += r["first_call_s"]
+            for k in totals:
+                totals[k] += r[k]
+        stages = {k: v if isinstance(v, int) else round(v, 6)
+                  for k, v in totals.items()}
         return {"entries": len(self._runs), "hits": self.hits,
                 "misses": self.misses,
-                "first_call_s": round(self.first_call_s, 3),
+                "first_call_s": round(first_call_s, 3),
+                **stages,
                 "devices": int(jax.device_count()),
                 "shard_topologies": tuple(sorted(self._topologies))}
 
@@ -458,7 +526,8 @@ class RunCache:
         if batched:
             fn = jax.vmap(fn, in_axes=(None, 0, None))
         fn = _TimedRun(
-            jax.jit(fn, donate_argnums=(1,) if donate else ()), self)
+            jax.jit(fn, donate_argnums=(1,) if donate else ()), self,
+            n_cycles)
         self._topologies.add(f"{CHANNEL_AXIS}:{int(shard)}" if shard
                              else "vmap")
         self._runs[key] = fn
@@ -619,15 +688,19 @@ class Simulator:
                 interval=interval if interval is not None else fcfg.interval,
                 read_ratio=(read_ratio if read_ratio is not None
                             else fcfg.read_ratio))
-        fp = fcfg.params()
+        # host phases as profiler spans, on the device trace's clock
         ff = self.fast_forward if fast_forward is None else fast_forward
-        run_fn = RUN_CACHE.get(self._cache_spec, self.controller, fcfg,
-                               n_cycles, trace=trace, replay=self.replay,
-                               telemetry=telemetry,
-                               shard=self._resolved_shard(),
-                               fast_forward=ff)
-        out = run_fn(self._dyn_params(), fp, jnp.uint32(seed))
-        out = jax.tree.map(np.asarray, out)
+        with jax.profiler.TraceAnnotation("sim.lookup"):
+            run_fn = RUN_CACHE.get(self._cache_spec, self.controller, fcfg,
+                                   n_cycles, trace=trace, replay=self.replay,
+                                   telemetry=telemetry,
+                                   shard=self._resolved_shard(),
+                                   fast_forward=ff)
+        with jax.profiler.TraceAnnotation("sim.launch"):
+            out = run_fn(self._dyn_params(), fcfg.params(),
+                         jnp.uint32(seed))
+        with jax.profiler.TraceAnnotation("sim.fetch"):
+            out = jax.tree.map(np.asarray, out)
         if telemetry:
             from repro import telemetry as T   # lazy: keeps core dep-free
             *rest, snaps = out
@@ -755,6 +828,18 @@ def _aggregate_stats(msys: MemorySystemSpec, chs: list, clk,
     )
 
 
+#: ``jax.named_scope`` names of the cycle loop's parts.  They reach the
+#: compiled program's op metadata (``op_name="…/while/body/<scope>/…"``),
+#: so a profiler trace can attribute each device operation to one part:
+#: ``frontend`` (insert, commit, finish), ``controller`` (each group's
+#: vmapped ``controller_step``), ``fold`` (stats fold, fused reduction
+#: and its ``psum``), ``horizon`` (fast-forward horizon, ``pmin`` and
+#: idle jump), ``trace_write`` (trace-buffer update) and
+#: ``telemetry_snap`` (window snapshot).
+SCOPES = ("frontend", "controller", "fold", "horizon", "trace_write",
+          "telemetry_snap")
+
+
 def make_run(spec, ccfg: C.ControllerConfig,
              fcfg: F.FrontendConfig, n_cycles: int, trace: bool,
              replay: F.ReplayStream | None = None,
@@ -868,35 +953,41 @@ def make_run(spec, ccfg: C.ControllerConfig,
         # returns it, so the macro-stepper can gate its horizon
         # computation on a busy/idle verdict that is uniform across
         # shards by construction (it rides the psum).
-        queues, draft = F.system_frontend_insert(
-            msys, fcfg, fp, sim.fs, tuple(g.cs.queue for g in sim.gs),
-            sim.clk, sys_layout, rp, bases)
+        # each part runs in its named scope (SCOPES)
+        with jax.named_scope("frontend"):
+            queues, draft = F.system_frontend_insert(
+                msys, fcfg, fp, sim.fs, tuple(g.cs.queue for g in sim.gs),
+                sim.clk, sys_layout, rp, bases)
         new_gs, evs = [], []
         for gi, (grp, dp) in enumerate(zip(groups, dps)):
             cs = sim.gs[gi].cs._replace(queue=queues[gi])
-            cs, ev = jax.vmap(
-                lambda s: C.controller_step(grp.cspec, dp, ccfg, s, sim.clk,
-                                            grp.link_latency))(cs)
-            # with telemetry, the gauge columns ride this same stats fold
-            # (the telemetry-off traced program is unchanged)
-            ch = _accum_channel_stats(grp.cspec, sim.gs[gi].ch, ev,
-                                      sim.clk, bool(telemetry_window))
+            with jax.named_scope("controller"):
+                cs, ev = jax.vmap(
+                    lambda s: C.controller_step(grp.cspec, dp, ccfg, s,
+                                                sim.clk, grp.link_latency))(cs)
+            with jax.named_scope("fold"):
+                # with telemetry, the gauge columns ride this same stats
+                # fold (the telemetry-off traced program is unchanged)
+                ch = _accum_channel_stats(grp.cspec, sim.gs[gi].ch, ev,
+                                          sim.clk, bool(telemetry_window))
             new_gs.append(GroupState(cs=cs, ch=ch))
             evs.append(ev)
-        absorb = F.absorb_locals(evs[0])
-        for ev in evs[1:]:
-            absorb = absorb + F.absorb_locals(ev)
-        # [probe-accept, stream-accept, probes-done, served, completion]
-        loc = jnp.concatenate([jnp.stack([draft.okp, draft.ok]), absorb])
-        if fast_forward:
-            issued = sum(jnp.sum((ev.cmd >= 0).astype(jnp.int32))
-                         for ev in evs)
-            loc = jnp.concatenate([loc, issued[None]])
-        if axis_name is not None:
-            loc = jax.lax.psum(loc, axis_name)
-        fs = F.frontend_commit(fcfg, fp, sim.fs, draft, loc[0], loc[1],
-                               F.paced_by_arrive(fcfg, rp))
-        fs = F.frontend_finish(fs, fp, loc[2], loc[3], loc[4])
+        with jax.named_scope("fold"):
+            absorb = F.absorb_locals(evs[0])
+            for ev in evs[1:]:
+                absorb = absorb + F.absorb_locals(ev)
+            # [probe-accept, stream-accept, probes-done, served, completion]
+            loc = jnp.concatenate([jnp.stack([draft.okp, draft.ok]), absorb])
+            if fast_forward:
+                issued = sum(jnp.sum((ev.cmd >= 0).astype(jnp.int32))
+                             for ev in evs)
+                loc = jnp.concatenate([loc, issued[None]])
+            if axis_name is not None:
+                loc = jax.lax.psum(loc, axis_name)
+        with jax.named_scope("frontend"):
+            fs = F.frontend_commit(fcfg, fp, sim.fs, draft, loc[0], loc[1],
+                                   F.paced_by_arrive(fcfg, rp))
+            fs = F.frontend_finish(fs, fp, loc[2], loc[3], loc[4])
         out = SimState(gs=tuple(new_gs), fs=fs, clk=sim.clk + 1)
         # trace ys stay a per-group tuple ((C_g, 2) leaves) until the
         # post-scan finalize — on the sharded path the gather happens on
@@ -961,6 +1052,12 @@ def make_run(spec, ccfg: C.ControllerConfig,
         return init._replace(
             fs=init.fs._replace(rng=seed | jnp.uint32(1)))
 
+    def snapshot(sim):
+        """Every group's window-boundary telemetry view at ``sim.clk``."""
+        with jax.named_scope("telemetry_snap"):
+            return tuple(_snap_telemetry(grp.cspec, g, sim.clk)
+                         for grp, g in zip(groups, sim.gs))
+
     def _scan_cycles(init, body):
         """Drive ``body`` over ``n_cycles`` honoring the telemetry
         windowing; returns ``(final SimState, per-group trace ys | None,
@@ -976,10 +1073,6 @@ def make_run(spec, ccfg: C.ControllerConfig,
         # segments.  Each boundary emits the CUMULATIVE counters (the
         # host diffs consecutive snapshots), so the final snapshot equals
         # the end-of-run aggregates bit-exactly by construction.
-        def snapshot(sim):
-            return tuple(_snap_telemetry(grp.cspec, g, sim.clk)
-                         for grp, g in zip(groups, sim.gs))
-
         W = telemetry_window
         n_full, rem = divmod(n_cycles, W)
         sim = init
@@ -1085,11 +1178,6 @@ def make_run(spec, ccfg: C.ControllerConfig,
         bufs0 = _init_trace_bufs(local_counts) if trace else None
         W = telemetry_window
         n_full = n_cycles // W if W else 0
-
-        def snapshot(sim):
-            return tuple(_snap_telemetry(grp.cspec, g, sim.clk)
-                         for grp, g in zip(groups, sim.gs))
-
         snaps0 = jax.tree.map(
             lambda s: jnp.zeros((n_full,) + s.shape, s.dtype),
             jax.eval_shape(snapshot, init)) if W else None
@@ -1106,36 +1194,40 @@ def make_run(spec, ccfg: C.ControllerConfig,
             out, ys, loc = body(sim)
             if trace:
                 z = jnp.int32(0)
-                bufs = tuple(
-                    jax.tree.map(
-                        lambda b, y: jax.lax.dynamic_update_slice(
-                            b, y[None].astype(b.dtype), (t0, z, z)),
-                        bufs[g], ys[g])
-                    for g in range(n_groups))
-            busy = (loc[0] + loc[1] + loc[5]) > 0
-            nxt_clk = out.clk
-            if axis_name is not None:
-                # the horizon reads this shard's channels, so it varies
-                # over the mesh axis: both cond branches must carry the
-                # same varying axes, and the pmin makes h uniform again
-                nxt_clk = _vary(nxt_clk, axis_name)
-            h = jax.lax.cond(busy, lambda _: nxt_clk,
-                             lambda _: _horizon(out, dps, fp), None)
-            if axis_name is not None:
-                h = jax.lax.pmin(h, axis_name)
-            cap = jnp.int32(n_cycles)
-            if W:
-                cap = jnp.minimum(cap, (t0 // W + 1) * W)
-            target = jnp.minimum(jnp.maximum(h, out.clk), cap)
-            nxt = _idle_jump(out, target)
+                with jax.named_scope("trace_write"):
+                    bufs = tuple(
+                        jax.tree.map(
+                            lambda b, y: jax.lax.dynamic_update_slice(
+                                b, y[None].astype(b.dtype), (t0, z, z)),
+                            bufs[g], ys[g])
+                        for g in range(n_groups))
+            with jax.named_scope("horizon"):
+                busy = (loc[0] + loc[1] + loc[5]) > 0
+                nxt_clk = out.clk
+                if axis_name is not None:
+                    # the horizon reads this shard's channels, so it
+                    # varies over the mesh axis: both cond branches must
+                    # carry the same varying axes, and the pmin makes h
+                    # uniform again
+                    nxt_clk = _vary(nxt_clk, axis_name)
+                h = jax.lax.cond(busy, lambda _: nxt_clk,
+                                 lambda _: _horizon(out, dps, fp), None)
+                if axis_name is not None:
+                    h = jax.lax.pmin(h, axis_name)
+                cap = jnp.int32(n_cycles)
+                if W:
+                    cap = jnp.minimum(cap, (t0 // W + 1) * W)
+                target = jnp.minimum(jnp.maximum(h, out.clk), cap)
+                nxt = _idle_jump(out, target)
             if W and n_full:        # n_cycles < W: tail snapshot only
-                snaps = jax.lax.cond(
-                    target % W == 0,
-                    lambda s: jax.tree.map(
-                        lambda b, v: jax.lax.dynamic_update_index_in_dim(
-                            b, v.astype(b.dtype), target // W - 1, 0),
-                        s, snapshot(nxt)),
-                    lambda s: s, snaps)
+                with jax.named_scope("telemetry_snap"):
+                    snaps = jax.lax.cond(
+                        target % W == 0,
+                        lambda s: jax.tree.map(
+                            lambda b, v: jax.lax.dynamic_update_index_in_dim(
+                                b, v.astype(b.dtype), target // W - 1, 0),
+                            s, snapshot(nxt)),
+                        lambda s: s, snaps)
             return nxt, steps + jnp.int32(1), bufs, snaps
 
         sim, steps, bufs, snaps = jax.lax.while_loop(

@@ -27,9 +27,13 @@ as possible:
      group overlaps device execution of the next, and at most
      `max_in_flight` groups' device buffers are ever live, so
      thousands-of-point sweeps never materialize all outputs at once.
-     A `repro.telemetry.Profiler` attributes the wall clock to
-     ``dispatch`` (compile + async call) vs ``collect`` (device sync +
-     host fold) spans, reported in ``meta["profile"]``.
+     A `repro.telemetry.Profiler` attributes the wall clock to the
+     phases ``dse.plan`` (expand, group, compile the spec, stack and
+     place the load points), ``dse.lookup`` (`RunCache.get`),
+     ``dse.dispatch`` (the async call; a program's first call compiles)
+     and ``dse.collect`` (device sync + host fold), reported in
+     ``meta["profile"]`` and, under a profiler session, as spans on the
+     device trace's clock.
 
 With `SweepSpec(capture_traces=...)` each group runs its *trace-emitting*
 program instead — still exactly one compiled program per group (the trace
@@ -161,8 +165,8 @@ def execute(spec: SweepSpec, cache: E.RunCache | None = None,
     fresh `RunCache()` to isolate compilations (tests do).  `devices`
     defaults to `jax.devices()`.  `max_in_flight` bounds how many groups'
     device buffers may be live at once (>= 1); `profiler` is an optional
-    `repro.telemetry.Profiler` to fold the dispatch/collect spans into
-    (one is created per call otherwise, reported in ``meta["profile"]``).
+    `repro.telemetry.Profiler` to record the phase spans into (one is
+    created per call otherwise, reported in ``meta["profile"]``).
     """
     from repro import telemetry as T    # lazy: keeps import order flexible
     cache = E.RUN_CACHE if cache is None else cache
@@ -171,10 +175,11 @@ def execute(spec: SweepSpec, cache: E.RunCache | None = None,
         raise ValueError("devices=[] — pass devices=None for jax.devices()"
                          " or a non-empty device list")
     prof = profiler if profiler is not None else T.Profiler(cache)
-    points = spec.expand()
-    if spec.lint_specs:
-        lint_sweep_systems(points)      # fail fast with a LintReport
-    groups = group_points(points)
+    with prof.span("dse.plan"):
+        points = spec.expand()
+        if spec.lint_specs:
+            lint_sweep_systems(points)      # fail fast with a LintReport
+        groups = group_points(points)
 
     n = len(points)
     cols = {k: np.zeros((n,), np.float64)
@@ -203,8 +208,11 @@ def execute(spec: SweepSpec, cache: E.RunCache | None = None,
 
     def _harvest():
         """Synchronize the OLDEST in-flight group and fold its results."""
-        g = inflight.popleft()
-        tc = time.perf_counter()
+        with prof.span("dse.collect"):
+            _fold(inflight.popleft())
+
+    def _fold(g):
+        """Wait for one dispatched group and fold it into the columns."""
         out = jax.block_until_ready(g["out"])
         members, idx = g["members"], g["idx"]
         msys, cspec = g["msys"], g["cspec"]
@@ -250,41 +258,36 @@ def execute(spec: SweepSpec, cache: E.RunCache | None = None,
         for j, i in enumerate(idx):
             cmd_counts[i] = np.asarray(stats.cmd_counts[j])
             cmd_names[i] = list(msys.cmd_names)
-        dt = time.perf_counter() - tc
-        prof.add("collect", dt)
-        g["meta"]["collect_s"] = round(dt, 3)
-        g["meta"]["wall_s"] = round(g["meta"]["dispatch_s"] + dt, 3)
 
     for key, members in groups.items():
-        td = time.perf_counter()
-        idx = [i for i, _ in members]
-        pts = [pt for _, pt in members]
-        sy, ccfg, fcfg = pts[0].system, pts[0].controller, pts[0].frontend
-        cspec = _compile_point_system(pts[0])
-        msys = as_system(cspec)
-        dp = tuple(D.dyn_params(g.cspec) for g in msys.groups)
-        fp = _front_params(pts, fcfg)
-        fp, pad = _shard_batch(fp, devices)
-        padded_total += pad
-        fn = cache.get(cspec, ccfg, fcfg, pts[0].n_cycles,
-                       trace=bool(capture), batched=True,
-                       telemetry=spec.telemetry, donate=True)
-        # async dispatch: jax returns un-synchronized arrays; the device
-        # churns through this group while the host dispatches the next
-        # (and harvests the oldest).  A program's FIRST call still blocks
-        # inside the cache's compile timer.
-        out = fn(dp, fp, jnp.uint32(spec.seed))
-        dt = time.perf_counter() - td
-        prof.add("dispatch", dt)
-        gm = {"system": sy.label, "n_points": len(pts),
-              "n_channels": pts[0].n_channels,
-              "n_spec_groups": msys.n_groups,
-              "mapper": fcfg.mapper, "padded": pad,
-              "dispatch_s": round(dt, 3)}
-        group_meta.append(gm)
+        with prof.span("dse.plan"):
+            idx = [i for i, _ in members]
+            pts = [pt for _, pt in members]
+            sy, ccfg, fcfg = (pts[0].system, pts[0].controller,
+                              pts[0].frontend)
+            cspec = _compile_point_system(pts[0])
+            msys = as_system(cspec)
+            dp = tuple(D.dyn_params(g.cspec) for g in msys.groups)
+            fp = _front_params(pts, fcfg)
+            fp, pad = _shard_batch(fp, devices)
+            padded_total += pad
+        with prof.span("dse.lookup"):
+            fn = cache.get(cspec, ccfg, fcfg, pts[0].n_cycles,
+                           trace=bool(capture), batched=True,
+                           telemetry=spec.telemetry, donate=True)
+        with prof.span("dse.dispatch"):
+            # async dispatch: jax returns un-synchronized arrays; the
+            # device churns through this group while the host dispatches
+            # the next (and harvests the oldest).  A program's FIRST call
+            # still blocks inside the cache's compile timer.
+            out = fn(dp, fp, jnp.uint32(spec.seed))
+        group_meta.append({"system": sy.label, "n_points": len(pts),
+                           "n_channels": pts[0].n_channels,
+                           "n_spec_groups": msys.n_groups,
+                           "mapper": fcfg.mapper, "padded": pad})
         inflight.append({"out": out, "members": members, "idx": idx,
                          "msys": msys, "cspec": cspec, "ccfg": ccfg,
-                         "fcfg": fcfg, "pad": pad, "meta": gm})
+                         "fcfg": fcfg, "pad": pad})
         while len(inflight) > max(1, int(max_in_flight)):
             _harvest()
     while inflight:
@@ -304,8 +307,9 @@ def execute(spec: SweepSpec, cache: E.RunCache | None = None,
         # group's last point (simulated, then dropped from the results)
         "padded_points": padded_total,
         "max_in_flight": max(1, int(max_in_flight)),
-        # dispatch vs collect wall attribution for the streamed pipeline,
-        # plus what event-horizon fast-forward bought across the sweep
+        # plan/lookup/dispatch/collect wall attribution for the streamed
+        # pipeline, plus what event-horizon fast-forward bought across
+        # the sweep
         "profile": {
             **prof.report(),
             "fast_forward": {

@@ -23,6 +23,10 @@ from repro.core import engine as E
 class Profiler:
     """Record named wall-time spans and RunCache accounting deltas.
 
+    Each span is also a ``jax.profiler.TraceAnnotation`` of the same name,
+    so under a profiler session it lies on the device trace's clock beside
+    the operations it launched (docs/observability.md §5).
+
     >>> prof = Profiler()
     >>> with prof.span("sweep"):
     ...     result = run_sweep(spec)
@@ -45,33 +49,27 @@ class Profiler:
     def span(self, name: str):
         t0 = time.perf_counter()
         try:
-            yield self
+            with jax.profiler.TraceAnnotation(name):
+                yield self
         finally:
             dt = time.perf_counter() - t0
             s = self._spans.setdefault(name, {"s": 0.0, "calls": 0})
             s["s"] += dt
             s["calls"] += 1
 
-    def add(self, name: str, seconds: float) -> None:
-        """Fold an externally-timed interval into span ``name`` — for
-        callers that interleave many short phases (the streaming sweep
-        executor's per-group dispatch/collect attribution) where a
-        context manager per slice would obscure the control flow."""
-        s = self._spans.setdefault(name, {"s": 0.0, "calls": 0})
-        s["s"] += float(seconds)
-        s["calls"] += 1
-
     def cache_stats(self) -> dict:
         """RunCache accounting since this profiler was constructed.
-        Numeric fields are deltas against the construction instant;
-        non-numeric fields (device/topology views) pass through as-is."""
-        delta_keys = {"entries", "hits", "misses", "first_call_s"}
+        Counters (entries, hits and misses, first-call seconds and their
+        compile stages) are deltas against the construction instant;
+        the device and topology views pass through as they are."""
+        delta_keys = {"entries", "hits", "misses", "first_call_s",
+                      *E.COMPILE_EVENTS.values()}
         now = self.cache.stats()
         out = {}
         for k, v in now.items():
             if k in delta_keys and isinstance(v, (int, float)):
                 base = self._base.get(k, 0)
-                out[k] = round(v - base, 3) if isinstance(v, float) \
+                out[k] = round(v - base, 6) if isinstance(v, float) \
                     else v - base
             else:
                 # topology views ("devices", "shard_topologies", future

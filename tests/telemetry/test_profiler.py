@@ -1,6 +1,7 @@
 """Host-side run profiler: RunCache public accounting, span recording,
 and the one-shot cold/warm characterization."""
 import numpy as np
+import pytest
 
 from repro import telemetry as T
 from repro.core import Simulator
@@ -10,6 +11,8 @@ from repro.core import engine as E
 def test_runcache_stats_public_api():
     s = E.RUN_CACHE.stats()
     assert set(s) == {"entries", "hits", "misses", "first_call_s",
+                      "trace_s", "lower_s", "compile_s", "cache_load_s",
+                      "persistent_hits", "persistent_misses",
                       "devices", "shard_topologies"}
     assert s["entries"] >= 0 and s["first_call_s"] >= 0.0
     assert s["devices"] >= 1
@@ -61,3 +64,74 @@ def test_sweep_reports_cache_accounting():
                             read_ratios=(1.0,), n_cycles=500))
     c = res.meta["cache"]
     assert set(c) >= {"entries", "hits", "misses", "first_call_s"}
+
+
+def test_runcache_counts_the_compile_stages_of_first_calls():
+    import jax.numpy as jnp
+    cache = E.RunCache()
+    prof = T.Profiler(cache)
+    sim = Simulator("DDR4", "DDR4_8Gb_x8", "DDR4_2400R")
+    # a cycle count no other test compiles, so that the program is new
+    # to this process and every stage runs
+    args = (sim._dyn_params(), sim.frontend.params(), jnp.uint32(3))
+    cache.get(sim.cspec, sim.controller, sim.frontend, 417)(*args)
+    first = cache.stats()
+    for stage in ("trace_s", "lower_s", "compile_s"):
+        assert first[stage] > 0, stage
+    assert first["compile_s"] <= first["first_call_s"] + 1e-3
+    assert first["persistent_hits"] == first["persistent_misses"] == 0
+    # the same, per program
+    (record,) = cache.first_calls()
+    assert record["n_cycles"] == 417
+    for stage in ("trace_s", "lower_s", "compile_s"):
+        assert record[stage] == pytest.approx(first[stage], abs=1e-6)
+    # a second identical call (and lookup) compiles nothing
+    cache.get(sim.cspec, sim.controller, sim.frontend, 417)(*args)
+    again = cache.stats()
+    assert {k: v for k, v in again.items() if k != "hits"} == \
+        {k: v for k, v in first.items() if k != "hits"}
+    # the profiler's view is the delta since it was made
+    delta = prof.cache_stats()
+    for stage in ("trace_s", "lower_s", "compile_s", "cache_load_s"):
+        assert delta[stage] == pytest.approx(again[stage], abs=1e-6)
+    assert len(cache.first_calls()) == 1
+    cache.clear()
+    assert cache.stats()["trace_s"] == 0.0
+    assert cache.first_calls() == []
+
+
+def test_entry_points_emit_their_spans_inside_the_callers_span(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.dse import SweepSpec, execute
+    sim = Simulator("DDR4", "DDR4_8Gb_x8", "DDR4_2400R")
+    spec = SweepSpec(systems=("DDR4",), intervals=(4.0, 2.0),
+                     read_ratios=(1.0,), n_cycles=300)
+    sim.run(300)
+    execute(spec)                   # both programs compiled beforehand
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("bench.call"):
+            sim.run(300)
+        with jax.profiler.TraceAnnotation("bench.call"):
+            execute(spec)
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+             for plane in ProfileData.from_file(path[0]).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith(("bench.", "sim.", "dse."))]
+    calls = sorted((s, e) for n, s, e in spans if n == "bench.call")
+    assert len(calls) == 2
+
+    def inside(call):
+        lo, hi = calls[call]
+        return [n for n, s, e in sorted(spans, key=lambda x: x[1])
+                if lo <= s and e <= hi and n != "bench.call"]
+    assert inside(0) == ["sim.lookup", "sim.launch", "sim.fetch"]
+    # one compile group: plan the sweep, then plan, look up, dispatch and
+    # collect the group
+    assert inside(1) == ["dse.plan", "dse.plan", "dse.lookup",
+                         "dse.dispatch", "dse.collect"]
