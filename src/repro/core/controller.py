@@ -16,16 +16,19 @@ masks over the request queue:
   * PRAC                        -> alert-driven recovery (RFM) that ordinary
     requests must not interfere with.
 
-All of it is vectorized: a predicate is `(PredCtx) -> bool[Q]`.
+All of it is vectorized: a predicate is `(PredCtx) -> bool[Q]`, and every
+per-slot read of state or of a constant table is a dense one-hot select
+(``device.pick`` / ``table_at`` / ``lut``), not a gather; only the
+BlockHammer sketch and PRAC alert lookups, off by default, still index.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import device as D
 from repro.core import spec as S
@@ -136,6 +139,8 @@ class PredCtx(NamedTuple):
     bank: jnp.ndarray         # (Q,) flat bank ids
     ru: jnp.ndarray           # (Q,) refresh-unit ids
     ref_urgent: jnp.ndarray   # (n_refresh_units,) refresh must go first
+    bank_hot: jnp.ndarray     # (n_banks, Q) one-hot of ``bank`` (D.onehot)
+    ru_hot: jnp.ndarray       # (n_refresh_units, Q) one-hot of ``ru``
 
 
 Predicate = Callable[..., jnp.ndarray]   # (cspec, ctx) -> bool[Q]
@@ -147,7 +152,7 @@ Predicate = Callable[..., jnp.ndarray]   # (cspec, ctx) -> bool[Q]
 
 def pred_refresh_urgency(cspec, ctx):
     """Block requests to a refresh unit whose refresh is overdue-urgent."""
-    return ~ctx.ref_urgent[ctx.ru]
+    return ~D.pick(ctx.ref_urgent, ctx.ru_hot)
 
 
 def pred_act2_exclusive(cspec, ctx):
@@ -155,8 +160,8 @@ def pred_act2_exclusive(cspec, ctx):
     ACT-2 candidates may issue (nothing may interrupt it)."""
     if not cspec.split_activation:
         return jnp.ones_like(ctx.cand_cmd, bool)
-    pending = ctx.cs.dev.row_state[ctx.bank] == D.ROW_ACTIVATING
-    deadline = ctx.cs.dev.act1_clk[ctx.bank] + ctx.dp.nAAD
+    pending = D.pick(ctx.cs.dev.row_state, ctx.bank_hot) == D.ROW_ACTIVATING
+    deadline = D.pick(ctx.cs.dev.act1_clk, ctx.bank_hot) + ctx.dp.nAAD
     urgent = pending & (ctx.clk + 2 >= deadline)       # slack of one slot
     is_act2 = ctx.cand_cmd == jnp.int32(cspec.id_ACT2)
     return jnp.where(jnp.any(urgent), is_act2 & urgent, True)
@@ -168,7 +173,7 @@ def pred_act2_follows_act1(cspec, ctx):
     if not cspec.split_activation:
         return jnp.ones_like(ctx.cand_cmd, bool)
     is_act2 = ctx.cand_cmd == jnp.int32(cspec.id_ACT2)
-    activating = ctx.cs.dev.row_state[ctx.bank] == D.ROW_ACTIVATING
+    activating = D.pick(ctx.cs.dev.row_state, ctx.bank_hot) == D.ROW_ACTIVATING
     return ~is_act2 | activating
 
 
@@ -270,15 +275,15 @@ class StepEvents(NamedTuple):
 # --------------------------------------------------------------------------
 
 
-def _candidates(cspec, dp, cs, clk, bank):
+def _candidates(cspec, dp, cs, clk, bank_hot):
     q = cs.queue
-    pre = jax.vmap(partial(D.prereq, cspec, dp, cs.dev),
-                   in_axes=(0, 0, 0, None))
-    cand_cmd, cand_row, open_hit = pre(q.is_write, q.sub, q.row, clk)
-    # dense (n_cmds, n_banks) earliest table + one (Q,) lookup — keeps the
-    # channel-vmapped pipeline vectorized (no per-slot gather loops)
+    cand_cmd, cand_row, open_hit = D.prereq(cspec, dp, cs.dev, q.is_write,
+                                            q.sub, q.row, clk)
+    # dense (n_cmds, n_banks) earliest table, read per slot by a one-hot
+    # select (cand_cmd is one of D.prereq_cmds; the bank is in range)
     table = D.earliest_ready_table(cspec, dp, cs.dev)
-    timing_ready = clk >= table[cand_cmd, bank]
+    timing_ready = clk >= D.table_at(table, cand_cmd, bank_hot,
+                                     D.prereq_cmds(cspec))
     return cand_cmd, cand_row, open_hit, timing_ready, table
 
 
@@ -301,43 +306,57 @@ def _refresh_plan(cspec, dp, cs, clk, cfg: ControllerConfig):
     if not cfg.refresh_enabled:
         due = jnp.zeros_like(due)
         urgent = jnp.zeros_like(urgent)
+    return due, urgent, _refresh_cmd(cspec, dev)
+
+
+def _refresh_cmd(cspec, dev):
+    """Per refresh unit, the refresh engine's next command: PREab while any
+    of its banks is open, else REFab."""
     banks_per_ru = cspec.n_banks // cspec.n_refresh_units
     any_open = jnp.any(
         dev.row_state.reshape(cspec.n_refresh_units, banks_per_ru)
         != D.ROW_CLOSED, axis=1)
-    ref_cmd = jnp.where(any_open, jnp.int32(cspec.id_PREab),
-                        jnp.int32(cspec.id_REFab))
-    return due, urgent, ref_cmd
+    return jnp.where(any_open, jnp.int32(cspec.id_PREab),
+                     jnp.int32(cspec.id_REFab))
+
+
+def _refresh_ready_at(cspec, table, ref_cmd):
+    """Per refresh unit ``u``, ``table[ref_cmd[u], u * banks_per_ru]``: the
+    earliest issue clock of its refresh command (PREab or REFab) at its
+    representative bank."""
+    banks_per_ru = cspec.n_banks // cspec.n_refresh_units
+    rep = jax.lax.slice(table, (0, 0), table.shape, (1, banks_per_ru))
+    return D.select_row(rep, ref_cmd, (cspec.id_PREab, cspec.id_REFab))
 
 
 def _ru_addr(cspec, ru):
     """Address-vector stand-in for a refresh-unit-scoped command."""
     nsub = len(cspec.levels) - 1
-    sub = jnp.zeros((nsub,), jnp.int32).at[0].set(ru)
-    return sub
+    return jnp.where(jnp.arange(nsub) == 0, ru, 0).astype(jnp.int32)
 
 
 def _try_issue_refresh(cspec, dp, cs, clk, due, urgent, ref_cmd,
-                       kind_mask_ok, table):
+                       cmd_ok, table):
     """Issue the refresh-engine command of the most-overdue due unit.
 
     Refresh is *opportunistic* until urgent: a merely-due refresh yields to
     pending requests targeting the same unit; an urgent one preempts (the
     ``refresh_urgency`` predicate blocks those requests at the same time).
     ``table`` is the pass's dense earliest-issue table; the refresh unit's
-    representative bank resolves its timing through the same lookup the
-    queue candidates use.
+    representative bank resolves its timing through it.  ``cmd_ok`` is the
+    pass's static per-command bus mask.  ``ru`` is an argmax over the
+    refresh units, so its one-hot reads are in range.
     """
     score = jnp.where(due, clk - cs.dev.last_ref, -1)
     ru = jnp.argmax(score)
-    cmd = ref_cmd[ru]
+    ru_hot = D.onehot(ru, cspec.n_refresh_units)
+    cmd = D.pick(ref_cmd, ru_hot)
     sub = _ru_addr(cspec, ru)
-    ok_kind = kind_mask_ok[cmd]
-    banks_per_ru0 = cspec.n_banks // cspec.n_refresh_units
-    ready = clk >= table[cmd, ru * jnp.int32(banks_per_ru0)]
+    ok_kind = D.lut(cmd_ok, cmd)
+    ready = clk >= D.pick(_refresh_ready_at(cspec, table, ref_cmd), ru_hot)
     q = cs.queue
     pending_here = jnp.any(q.valid & (q.sub[:, 0] == ru))
-    may_go = urgent[ru] | ~pending_here
+    may_go = D.pick(urgent, ru_hot) | ~pending_here
     do = jnp.any(due) & ready & ok_kind & may_go
     dev = D.issue(cspec, dp, cs.dev, cmd, sub, jnp.int32(0), clk, do)
     # PRAC: recovery resets the unit's activation counters
@@ -352,7 +371,8 @@ def _try_issue_refresh(cspec, dp, cs, clk, due, urgent, ref_cmd,
 def _select_and_issue(cspec, dp, cs, clk, cfg, preds, kind_ok, sched_fn,
                       link_latency: int = 0):
     """One pass of the base pipeline restricted to commands with
-    kind_ok[kind] == True (dual C/A runs this twice, paper §2).
+    kind_ok[kind] == True (a static mask; dual C/A runs this twice, paper
+    §2).
 
     ``link_latency`` (static, cycles) models a CXL-style link in front of
     this channel: a request is not visible to the controller until
@@ -360,18 +380,20 @@ def _select_and_issue(cspec, dp, cs, clk, cfg, preds, kind_ok, sched_fn,
     ``link_latency`` cycles to cross back — probe completions therefore
     carry ``2 * link_latency`` of round-trip link time end to end."""
     q = cs.queue
-    bank = jax.vmap(partial(D.flat_bank, cspec))(q.sub)
+    bank = D.flat_bank(cspec, q.sub)
+    ru = D.refresh_unit(cspec, q.sub)
+    bank_hot = D.onehot(bank, cspec.n_banks)
     cand_cmd, cand_row, open_hit, timing_ready, table = _candidates(
-        cspec, dp, cs, clk, bank)
-    ru = q.sub[:, 0]
+        cspec, dp, cs, clk, bank_hot)
 
     due, urgent, ref_cmd = _refresh_plan(cspec, dp, cs, clk, cfg)
     ctx = PredCtx(dp=dp, cs=cs, clk=clk, cand_cmd=cand_cmd,
                   cand_row=cand_row, open_hit=open_hit, bank=bank, ru=ru,
-                  ref_urgent=urgent)
+                  ref_urgent=urgent, bank_hot=bank_hot,
+                  ru_hot=D.onehot(ru, cspec.n_refresh_units))
 
-    kind_mask = jnp.asarray(cspec.cmd_kind)
-    cand_kind_ok = kind_ok[kind_mask[cand_cmd]]
+    cmd_ok = np.asarray(kind_ok)[cspec.cmd_kind]     # static, per command
+    cand_kind_ok = D.lut(cmd_ok, cand_cmd)
 
     mask = q.valid & timing_ready & cand_kind_ok
     if link_latency:
@@ -386,28 +408,30 @@ def _select_and_issue(cspec, dp, cs, clk, cfg, preds, kind_ok, sched_fn,
     deferred = jnp.sum(pre_pred & ~mask)
 
     # refresh engine first (its commands obey the same kind restriction)
-    ref_kind_ok = kind_ok[kind_mask]
     cs, ref_issued, ref_cmd_done, ref_bank = _try_issue_refresh(
-        cspec, dp, cs, clk, due, urgent, ref_cmd, ref_kind_ok, table)
+        cspec, dp, cs, clk, due, urgent, ref_cmd, cmd_ok, table)
 
     hit_ready = jnp.any(mask & open_hit) & ~ref_issued
     slot, ok = sched_fn(mask & ~ref_issued, open_hit, q.arrive)
     do = ok & ~ref_issued
 
-    cmd = cand_cmd[slot]
-    sub = q.sub[slot]
-    rowv = cand_row[slot]
+    # the chosen slot's fields, read through its one-hot (slot is an
+    # argmin over the queue, so in range)
+    slot_hit = D.onehot(slot, q.valid.shape[0])
+    cmd = D.pick(cand_cmd, slot_hit)
+    sub = D.pick(q.sub, slot_hit)
+    rowv = D.pick(cand_row, slot_hit)
+    arrive = D.pick(q.arrive, slot_hit)
     dev = D.issue(cspec, dp, cs.dev, cmd, sub, rowv, clk, do)
 
-    fx = jnp.asarray(cspec.cmd_fx)[cmd]
+    fx = D.lut(cspec.cmd_fx, cmd)
     fin_rd = do & ((fx & S.FX_FINAL_RD) != 0)
     fin_wr = do & ((fx & S.FX_FINAL_WR) != 0)
     served = fin_rd | fin_wr
-    slot_hit = jnp.arange(q.valid.shape[0], dtype=jnp.int32) == slot
     valid = q.valid & ~(slot_hit & served)
 
     # row-hit streak bookkeeping (FRFCFS-Cap support)
-    b = bank[slot]
+    b = D.pick(bank, slot_hit)
     b_hit = jnp.arange(cspec.n_banks, dtype=jnp.int32) == b
     streak = cs.hit_streak
     streak = jnp.where(served & b_hit, streak + 1, streak)
@@ -428,7 +452,7 @@ def _select_and_issue(cspec, dp, cs, clk, cfg, preds, kind_ok, sched_fn,
         is_open_cmd = do & (cmd == jnp.int32(opener))
         prac = jnp.where(is_open_cmd & b_hit, prac + 1, prac)
 
-    probe = fin_rd & q.is_probe[slot]
+    probe = fin_rd & D.pick(q.is_probe, slot_hit)
     completion = clk + dp.read_latency
     if link_latency:
         # completion-boundary link latency: the data crosses the link back
@@ -439,10 +463,10 @@ def _select_and_issue(cspec, dp, cs, clk, cfg, preds, kind_ok, sched_fn,
         bank=jnp.where(do, b,
                        jnp.where(ref_issued, ref_bank, jnp.int32(-1))),
         row=jnp.where(do, rowv, jnp.int32(-1)),
-        arrive=jnp.where(do, q.arrive[slot], jnp.int32(-1)),
+        arrive=jnp.where(do, arrive, jnp.int32(-1)),
         hit_ready=hit_ready,
         served_read=fin_rd, served_write=fin_wr, served_probe=probe,
-        probe_latency=jnp.where(probe, completion - q.arrive[slot], 0),
+        probe_latency=jnp.where(probe, completion - arrive, 0),
         probe_completion=jnp.where(probe, completion, 0),
         deferred=deferred,
     )
@@ -491,12 +515,11 @@ def channel_horizon(cspec: CompiledSpec, dp: D.DynParams,
       halves on those cycles, so they must be executed, not skipped).
     """
     q = cs.queue
-    bank = jax.vmap(partial(D.flat_bank, cspec))(q.sub)
-    pre = jax.vmap(partial(D.prereq, cspec, dp, cs.dev),
-                   in_axes=(0, 0, 0, None))
-    cand_cmd, _, _ = pre(q.is_write, q.sub, q.row, clk)
+    bank_hot = D.onehot(D.flat_bank(cspec, q.sub), cspec.n_banks)
+    cand_cmd, _, _ = D.prereq(cspec, dp, cs.dev, q.is_write, q.sub, q.row,
+                              clk)
     table = D.earliest_ready_table(cspec, dp, cs.dev)
-    t_slot = table[cand_cmd, bank]
+    t_slot = D.table_at(table, cand_cmd, bank_hot, D.prereq_cmds(cspec))
     if link_latency:
         t_slot = jnp.maximum(t_slot, q.arrive + jnp.int32(link_latency))
     h = jnp.min(jnp.where(q.valid, t_slot, HORIZON_MAX),
@@ -509,14 +532,8 @@ def channel_horizon(cspec: CompiledSpec, dp: D.DynParams,
                 (cs.prac_count >= cfg.prac_threshold).reshape(
                     cspec.n_refresh_units, banks_per_ru), axis=1)
             due_t = jnp.where(alert, clk, due_t)
-        any_open = jnp.any(
-            cs.dev.row_state.reshape(cspec.n_refresh_units, banks_per_ru)
-            != D.ROW_CLOSED, axis=1)
-        ref_cmd = jnp.where(any_open, jnp.int32(cspec.id_PREab),
-                            jnp.int32(cspec.id_REFab))
-        rep = jnp.arange(cspec.n_refresh_units, dtype=jnp.int32) \
-            * jnp.int32(banks_per_ru)
-        h = jnp.minimum(h, jnp.min(jnp.maximum(due_t, table[ref_cmd, rep])))
+        ref_at = _refresh_ready_at(cspec, table, _refresh_cmd(cspec, cs.dev))
+        h = jnp.minimum(h, jnp.min(jnp.maximum(due_t, ref_at)))
     if cspec.data_clock_sync:
         cu = cs.dev.clock_until
         h = jnp.minimum(h, jnp.min(jnp.where(cu > clk, cu, HORIZON_MAX)))
@@ -570,9 +587,9 @@ def controller_step(cspec: CompiledSpec, dp: D.DynParams, cfg: ControllerConfig,
     n_kinds = 4
 
     if cspec.dual_command_bus:
-        col_ok = jnp.asarray(
+        col_ok = np.asarray(
             [k in (S.KIND_COL, S.KIND_SYNC) for k in range(n_kinds)])
-        row_ok = jnp.asarray(
+        row_ok = np.asarray(
             [k in (S.KIND_ROW, S.KIND_REF) for k in range(n_kinds)])
         cs, ev_col = _select_and_issue(cspec, dp, cs, clk, cfg, preds,
                                        col_ok, sched_fn, link_latency)
@@ -580,7 +597,7 @@ def controller_step(cspec: CompiledSpec, dp: D.DynParams, cfg: ControllerConfig,
                                        row_ok, sched_fn, link_latency)
         events = _pack_events(ev_col, ev_row)
     else:
-        all_ok = jnp.ones((n_kinds,), bool)
+        all_ok = np.ones((n_kinds,), bool)
         cs, ev = _select_and_issue(cspec, dp, cs, clk, cfg, preds, all_ok,
                                    sched_fn, link_latency)
         events = _pack_events(ev)

@@ -118,15 +118,88 @@ def node_per_level(cspec: CompiledSpec, addr_sub: jnp.ndarray) -> jnp.ndarray:
 
 
 def flat_bank(cspec: CompiledSpec, addr_sub: jnp.ndarray) -> jnp.ndarray:
+    """Flat bank id of an address, or of each address along the leading
+    axes of ``addr_sub`` (``(..., L-1)``)."""
     counts = cspec.level_counts
     flat = jnp.int32(0)
     for i in range(1, len(counts)):
-        flat = flat * jnp.int32(int(counts[i])) + addr_sub[i - 1]
+        flat = flat * jnp.int32(int(counts[i])) + addr_sub[..., i - 1]
     return flat
 
 
 def refresh_unit(cspec: CompiledSpec, addr_sub: jnp.ndarray) -> jnp.ndarray:
-    return addr_sub[0]
+    return addr_sub[..., 0]
+
+
+# --------------------------------------------------------------------------
+# Dense lookups (no gathers)
+# --------------------------------------------------------------------------
+#
+# XLA:TPU lowers a gather under the engine's (batch x channel) vmap nesting
+# to a near-serial loop over its elements, so the cycle body reads state at
+# a per-slot index by compare-select-reduce over the small static axis the
+# index ranges over (banks, refresh units, commands, queue slots).  Each
+# helper equals plain indexing for an in-range index; every caller's index
+# is in range (it says why), so jnp's index clamp never applies.  The
+# indexed axis comes first in a one-hot, so the reductions run down it,
+# across vector registers, and not across the lanes of one.
+
+def onehot(idx, n: int) -> jnp.ndarray:
+    """``arange(n) == idx`` with the ``n`` axis first: the one-hot of an
+    index into a static axis of ``n`` entries, shape ``(n,) + idx.shape``."""
+    idx = jnp.asarray(idx)
+    return jnp.arange(n, dtype=jnp.int32).reshape((n,) + (1,) * idx.ndim) \
+        == idx
+
+
+def pick(x: jnp.ndarray, hot: jnp.ndarray) -> jnp.ndarray:
+    """``x[i]`` given ``hot = onehot(i, n)``, for ``x`` of shape ``(n,) +
+    tail``: shape ``i.shape + tail``.  Exactly one entry is selected, so
+    the sum (``any`` for bools) is exact and its neutral 0 never meets a
+    stored value (``NEG`` entries come through unchanged)."""
+    tail = x.shape[1:]
+    hot = hot.reshape(hot.shape + (1,) * len(tail))
+    x = x.reshape(x.shape[:1] + (1,) * (hot.ndim - 1 - len(tail)) + tail)
+    if x.dtype == jnp.bool_:
+        return jnp.any(hot & x, axis=0)
+    return jnp.sum(jnp.where(hot, x, 0), axis=0, dtype=x.dtype)
+
+
+def select_row(table: jnp.ndarray, row, rows=None) -> jnp.ndarray:
+    """``table[row]``, ``row`` broadcast against ``table.shape[1:]``: a
+    select chain over ``rows``, the static row ids ``row`` can hold
+    (default: all).  The last of them is not compared, so a ``row`` outside
+    ``rows`` reads it."""
+    rows = range(table.shape[0]) if rows is None else rows
+    *rest, last = [int(r) for r in rows]     # static indices: slices
+    shape = jnp.broadcast_shapes(jnp.shape(row), table.shape[1:])
+    out = jnp.broadcast_to(table[last], shape)
+    for r in rest:
+        out = jnp.where(row == r, table[r], out)
+    return out
+
+
+def table_at(table: jnp.ndarray, cmd, bank_hot, cmds=None) -> jnp.ndarray:
+    """``table[cmd, bank]`` of the ``(n_cmds, n_banks)`` earliest-issue
+    table per element of ``cmd``, given ``bank_hot = onehot(bank,
+    n_banks)``: each element's command row (:func:`select_row` over
+    ``cmds``, the ids ``cmd`` can hold), then its bank."""
+    cmd = jnp.asarray(cmd)
+    by_bank = table.reshape(table.shape + (1,) * cmd.ndim)
+    rows = select_row(by_bank, cmd, cmds)          # (n_banks,) + cmd.shape
+    return jnp.sum(jnp.where(bank_hot, rows, 0), axis=0, dtype=table.dtype)
+
+
+def lut(values, idx) -> jnp.ndarray:
+    """``values[idx]`` for a static numpy table: a select chain over the
+    entries that differ from the last (the constant tables indexed by
+    command: ``cmd_fx``, ``cmd_scope``, a pass's command-kind mask)."""
+    values = np.asarray(values)
+    out = jnp.full(jnp.shape(idx), values[-1], values.dtype)
+    for c in range(len(values) - 1):
+        if values[c] != values[-1]:
+            out = jnp.where(idx == c, values[c], out)
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -168,10 +241,11 @@ def earliest_ready_table(cspec: CompiledSpec, dp: DynParams,
     trace time into static slices: each constraint row reads its level's
     node timestamps with a static slice of ``last_issue`` and broadcasts
     them to banks with a static ``repeat`` — no gathers or scatters at
-    all, which is what keeps the channel-vmapped selection pipeline
-    vectorized (dynamic gathers serialize under nested vmap on CPU/TPU).
-    The controller then resolves a queue slot's readiness with a single
-    ``table[cmd, bank]`` lookup.
+    all (dynamic gathers serialize under nested vmap on CPU/TPU).  The
+    controller reads a queue slot's entry with :func:`table_at`, a dense
+    select over the table's commands and banks: a ``table[cmd, bank]``
+    gather per slot would serialize the same way, and on the TPU it cost
+    more than building the table.
     """
     n_banks = cspec.n_banks
     sizes = np.asarray(cspec.level_counts, np.int64)
@@ -209,17 +283,34 @@ def timing_ok(cspec, dp, state, cmd, addr_sub, clk) -> jnp.ndarray:
 # Prerequisite decode (paper §2: per-standard request -> next command)
 # --------------------------------------------------------------------------
 
+def prereq_cmds(cspec: CompiledSpec) -> tuple:
+    """The command ids :func:`prereq` can return."""
+    ids = {cspec.id_ACT1 if cspec.split_activation else cspec.id_ACT,
+           cspec.id_PRE, cspec.id_RD, cspec.id_WR}
+    if cspec.split_activation:
+        ids.add(cspec.id_ACT2)
+    if cspec.data_clock_sync:
+        ids |= {c for c in (cspec.id_CAS_RD, cspec.id_CAS_WR,
+                            cspec.id_RCKSTRT) if c >= 0}
+    return tuple(sorted(ids))
+
+
 def prereq(cspec: CompiledSpec, dp: DynParams, state: DeviceState,
            is_write: jnp.ndarray, addr_sub: jnp.ndarray, row: jnp.ndarray,
            clk: jnp.ndarray):
-    """Next command needed to advance a request.
+    """Next command needed to advance a request, or each request along the
+    leading axes of ``is_write``, ``addr_sub`` (``(..., L-1)``) and ``row``
+    (the controller passes its whole queue).
 
-    Returns (cmd, cmd_row): cmd_row is the row the command actually targets
-    (ACT-2 completes the *pending* activation row, not the request's row).
+    Returns (cmd, cmd_row, open_hit): cmd_row is the row the command
+    actually targets (ACT-2 completes the *pending* activation row, not the
+    request's row).  The bank and refresh-unit state reads are one-hot
+    selects (:func:`pick`); an address's indices lie inside the
+    organisation (the mapper decodes them so, and an empty queue slot holds
+    zeros), so its bank and refresh unit are in range.
     """
-    bank = flat_bank(cspec, addr_sub)
-    ru = refresh_unit(cspec, addr_sub)
-    rs = state.row_state[bank]
+    bank_hot = onehot(flat_bank(cspec, addr_sub), cspec.n_banks)
+    rs = pick(state.row_state, bank_hot)
     open_hit = rs == row
     closed = rs == ROW_CLOSED
     activating = rs == ROW_ACTIVATING
@@ -227,7 +318,8 @@ def prereq(cspec: CompiledSpec, dp: DynParams, state: DeviceState,
     final = jnp.where(is_write, jnp.int32(cspec.id_WR), jnp.int32(cspec.id_RD))
     col_cmd = final
     if cspec.data_clock_sync:
-        clock_on = clk < state.clock_until[ru]
+        ru_hot = onehot(refresh_unit(cspec, addr_sub), cspec.n_refresh_units)
+        clock_on = clk < pick(state.clock_until, ru_hot)
         sync = jnp.where(is_write,
                          jnp.int32(cspec.id_CAS_WR if cspec.id_CAS_WR >= 0 else cspec.id_RCKSTRT),
                          jnp.int32(cspec.id_CAS_RD if cspec.id_CAS_RD >= 0 else cspec.id_RCKSTRT))
@@ -243,8 +335,10 @@ def prereq(cspec: CompiledSpec, dp: DynParams, state: DeviceState,
         cmd = jnp.where(closed, opener,
               jnp.where(open_hit, col_cmd, jnp.int32(cspec.id_PRE)))
 
-    cmd_row = jnp.where(cmd == jnp.int32(cspec.id_ACT2),
-                        state.act1_row[bank], row) if cspec.split_activation else row
+    cmd_row = row
+    if cspec.split_activation:
+        cmd_row = jnp.where(cmd == jnp.int32(cspec.id_ACT2),
+                            pick(state.act1_row, bank_hot), row)
     return cmd, cmd_row, open_hit
 
 
@@ -263,9 +357,11 @@ def issue(cspec: CompiledSpec, dp: DynParams, state: DeviceState,
     while these elementwise forms vectorize across all batch dimensions.
     The arrays are small (nodes x cmds, plus the tiny windowed ring), so
     the extra flops are noise next to the removed gather/scatter loops.
+    The constant per-command tables are read the same way (:func:`lut`);
+    ``cmd`` is a command id of the standard.
     """
     nodes = node_per_level(cspec, addr_sub)                    # (L,)
-    scope = jnp.asarray(cspec.cmd_scope)[cmd]
+    scope = lut(cspec.cmd_scope, cmd)
     lvl_idx = jnp.arange(len(cspec.levels), dtype=jnp.int32)
     upd_mask = (lvl_idx <= scope) & enable                     # ancestors+self
 
@@ -283,13 +379,13 @@ def issue(cspec: CompiledSpec, dp: DynParams, state: DeviceState,
         # scope mask is implied by ring_cmd == cmd
         r_cmd = jnp.asarray(cspec.ring_cmd)
         r_node = jnp.asarray(cspec.ring_node)
-        r_level = jnp.asarray(cspec.ring_level)
-        entry_hit = (r_cmd == cmd) & (nodes[r_level] == r_node) & enable
+        r_nodes = jnp.stack([nodes[int(lv)] for lv in cspec.ring_level])
+        entry_hit = (r_cmd == cmd) & (r_nodes == r_node) & enable
         shifted = jnp.concatenate(
             [jnp.full_like(ring[:, :1], clk), ring[:, :-1]], axis=1)
         ring = jnp.where(entry_hit[:, None], shifted, ring)
 
-    fx = jnp.asarray(cspec.cmd_fx)[cmd]
+    fx = lut(cspec.cmd_fx, cmd)
     bank = flat_bank(cspec, addr_sub)
     ru = refresh_unit(cspec, addr_sub)
     bank_hit = jnp.arange(cspec.n_banks, dtype=jnp.int32) == bank

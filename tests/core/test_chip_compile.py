@@ -4,10 +4,14 @@ Each test compiles for a described ``v5e:2x2`` topology, which needs the
 TPU compiler but no chip: nothing runs, so these say nothing about
 results or speed.  They catch what the chip's compiler refuses (a
 program that does not fit 16 GB of HBM, a collective it cannot place)
-before a chip run does.  The topology is described inside a fixture so
-that importing this file never loads the TPU library.
+before a chip run does, and a gather left in the cycle body's controller
+or horizon (XLA:TPU runs one under the engine's vmap nesting as a
+near-serial loop over its elements).  The topology is described inside a
+fixture so that importing this file never loads the TPU library; the
+jaxpr twin of the gather check needs no TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +21,7 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import ControllerConfig, FrontendConfig, compile_spec
+from repro.core import controller as C
 from repro.core import device as D
 from repro.core import engine as E
 from repro.core import frontend as F
@@ -24,6 +29,12 @@ from repro.core import frontend as F
 #: HBM of one TPU v5e chip (Google Cloud documentation, "TPU v5e")
 V5E_HBM_BYTES = 16e9
 HBM3 = ("HBM3", "HBM3_16Gb", "HBM3_5200")
+DDR5_2R = ("DDR5", "DDR5_16Gb_x8_2R", "DDR5_4800B")
+#: one standard of each controller path: dual command bus, split
+#: activation, data-clock sync
+GATHER_FREE = {"DDR5_2R": DDR5_2R, "HBM3": HBM3,
+               "LPDDR5_2R": ("LPDDR5", "LPDDR5_8Gb_x16_2R", "LPDDR5_6400"),
+               "GDDR7": ("GDDR7", "GDDR7_16Gb_x32", "GDDR7_32")}
 
 
 @pytest.fixture(scope="module")
@@ -108,3 +119,60 @@ def test_hbm3_16ch_channel_sharded_run_compiles_for_four_chips(
     _fits_one_chip(compiled)
     # the per-cycle psum/pmin of the sharded loop must cross the chips
     assert "all-reduce" in compiled.as_text()
+
+
+def _body_gathers(hlo_text):
+    """``(op_name, shape)`` of every gather whose op name lies in the loop
+    body's ``controller`` or ``horizon`` scope."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = re.search(r"= (\S+) gather\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if m and name and re.search(r"while/body/(controller|horizon)/",
+                                    name.group(1)):
+            out.append((name.group(1), m.group(1)))
+    return out
+
+
+def test_ddr5_8ch_sweep_body_has_no_gather(one_chip, no_compile_cache):
+    """The 24-point DDR5 8-channel 2R sweep program (the benchmark's
+    ``sweep24`` shapes) keeps no gather in its controller or horizon;
+    BlockHammer and PRAC, the only lookups left by index, are off."""
+    cspec = compile_spec(*DDR5_2R, channels=8)
+    fcfg = FrontendConfig()
+    fp = F.stack_params([(1.0 + 0.5 * i, 0.7) for i in range(24)],
+                        fcfg.probe_gap)
+    fn = jax.vmap(E.make_run(cspec, ControllerConfig(), fcfg, 50_000,
+                             trace=False), in_axes=(None, 0, None))
+    text = _compile(fn, _args(cspec, fp), one_chip).as_text()
+    assert "while/body/controller/" in text      # the scopes are named
+    assert _body_gathers(text) == []
+
+
+def _count_prims(jaxpr, name):
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for p in eqn.params.values():
+            for q in (p if isinstance(p, (tuple, list)) else (p,)):
+                inner = getattr(q, "jaxpr", q)
+                if hasattr(inner, "eqns"):
+                    n += _count_prims(inner, name)
+    return n
+
+
+@pytest.mark.parametrize("part", ["controller_step", "channel_horizon"])
+@pytest.mark.parametrize("std", sorted(GATHER_FREE))
+def test_controller_and_horizon_jaxpr_have_no_gather(std, part):
+    """The jaxpr twin of the described-chip check, for the tier-1 run
+    where no TPU compiler is installed: ``controller_step`` and
+    ``channel_horizon`` vmapped over 4 channels trace no gather."""
+    cspec = compile_spec(*GATHER_FREE[std], channels=4)
+    ccfg = ControllerConfig()
+    dp = D.dyn_params(cspec)
+    cs = jax.vmap(lambda _: C.init_ctrl_state(cspec, ccfg.queue_depth))(
+        jnp.arange(4))
+    fn = getattr(C, part)
+    jx = jax.make_jaxpr(jax.vmap(
+        lambda s: fn(cspec, dp, ccfg, s, jnp.int32(100))))(cs)
+    assert _count_prims(jx.jaxpr, "gather") == 0
