@@ -8,8 +8,9 @@ set-up, then for each of ``--seeds`` seeds one call of the timed path and
 the comparison of the points a run would sample with the plain
 reference (the lower reading: sound runs of the program), and for each
 of ``--control-seeds`` seeds the same points computed by the control (the
-reference with tRCD one cycle short, ``reference.simulate(control=True)``) in
-the program's place (the upper reading).  The benchmark's own runs never
+configuration's reference with one published guarantee broken, its
+``simulate(control=True)``; ``bench/reference.py``: tRCD one cycle short)
+in the program's place (the upper reading).  The benchmark's own runs never
 run this.  Prints one JSON line per seed and a summary line.
 """
 from __future__ import annotations
@@ -70,7 +71,8 @@ def main(argv=None) -> int:
             upper.append(row["control"])
         print(json.dumps(row), flush=True)
     print(json.dumps({"workload": args.workload, "device": dev,
-                      "control": "nRCD one cycle short",
+                      "reference": os.path.relpath(config["reference"],
+                                                   ROOT),
                       "lower_reading": max(lower) if lower else None,
                       "upper_reading": min(upper) if upper else None,
                       "program": lower, "controls": upper}), flush=True)

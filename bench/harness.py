@@ -1,17 +1,32 @@
 """The chip benchmark's harness: everything between the command line and
-the traffic kinds, metric readers and reference.
+the traffic kinds, metric readers and references.
 
 A cell is found by its name in ``BENCHMARK.json``; its configuration file
 and its traffic file (``bench/traffic/<traffic>.json``) are data.  The
 traffic file's ``kind`` names the runner in ``bench/kinds/<kind>.py``;
-each per-layer metric is read by ``bench/metrics/<metric>.py``.  A later
-cell, traffic mix or per-layer metric is therefore added by adding files.
+each per-layer metric is read by ``bench/metrics/<metric>.py``; the
+configuration file's ``reference`` key names the plain reference that
+checks it (default ``bench/reference.py``).  A later cell, traffic mix,
+per-layer metric or deployment is therefore added by adding files.
+
+A reference module, a file under ``bench/`` loaded by path:
+
+- has ``simulate(config, interval, read_ratio, seed, n_cycles,
+  control=False) -> {leaf: np.ndarray}``, keyed as
+  ``devices.stats_leaves`` keys the program's ``Stats``;
+- has ``NAMES``, its command names in the program's command order;
+- imports nothing from ``repro`` (``bench/`` is on ``sys.path``, so it
+  may ``import reference`` to reuse that module's code);
+- says in its docstring which one published guarantee ``control=True``
+  breaks.
+
+The kind's ``CHECKED`` picks which of its leaves are compared.
 
 One run: set-up (load the program's modules, name the devices, build the
 cell's runner, compile or load its one program with a light-load call),
 then the window (calls with seeds derived from ``--seed`` until
 ``--seconds`` have passed), then the comparison of a sample of the
-window's results with the plain reference (``bench/reference.py``).
+window's results with the configuration's plain reference.
 
 A traced run (``--trace 1``) makes one call of the timed program, for the
 exact counts and the check, then one call under the JAX profiler of the
@@ -76,28 +91,70 @@ def load_benchmark(root: str = ROOT) -> dict:
     return load_json(path)
 
 
+def _import_path(path: str, module_name: str):
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_plugin(kind: str, name: str, root: str = ROOT):
     """Import ``bench/<kind>/<name>.py`` under ``root`` by path (names may
     hold dots)."""
     path = os.path.join(root, "bench", kind, f"{name}.py")
     if not os.path.exists(path):
         raise BenchError(f"no {kind} reader {name!r} ({path})")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _import_path(path, f"bench_{kind}_{name.replace('.', '_')}")
+
+
+#: the plain reference of a configuration whose file names none
+DEFAULT_REFERENCE = "bench/reference.py"
+_REFERENCES = {}
+
+
+def reference_path(config: dict, root: str = ROOT) -> str:
+    """Absolute path of the configuration's plain reference: its
+    ``reference`` key, a path under ``root``'s ``bench/``, else
+    :data:`DEFAULT_REFERENCE`."""
+    rel = config.get("reference", DEFAULT_REFERENCE)
+    path = os.path.normpath(os.path.join(root, rel))
+    bench = os.path.join(os.path.normpath(root), "bench")
+    if os.path.commonpath([path, bench]) != bench:
+        raise BenchError(f"reference {rel!r} is not under {bench}")
+    if not os.path.isfile(path):
+        raise BenchError(f"no reference module {rel!r} ({path})")
+    return path
+
+
+def load_reference(path: str):
+    """The reference module at ``path``, imported once per process."""
+    if path not in _REFERENCES:
+        _REFERENCES[path] = _import_path(
+            path, f"bench_reference_{len(_REFERENCES)}")
+    return _REFERENCES[path]
+
+
+def load_config(bench: dict, name: str, root: str = ROOT) -> dict:
+    """The named configuration's file, with ``reference`` made the
+    absolute path of its plain reference (checked to exist)."""
+    confs = {c["name"]: c for c in bench["configs"]}
+    if name not in confs:
+        raise BenchError(f"unknown configuration {name!r}; known: "
+                         f"{sorted(confs)}")
+    config = load_json(os.path.join(root, confs[name]["file"]))
+    config["reference"] = reference_path(config, root)
+    return config
 
 
 def resolve_cell(bench: dict, workload: str, root: str = ROOT) -> tuple:
-    """``(cell, config, traffic)`` of the named cell."""
+    """``(cell, config, traffic)`` of the named cell (the configuration as
+    :func:`load_config` gives it)."""
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise BenchError(f"unknown workload {workload!r}; known: "
                          f"{sorted(cells)}")
     cell = cells[workload]
-    confs = {c["name"]: c for c in bench["configs"]}
-    config = load_json(os.path.join(root, confs[cell["config"]]["file"]))
+    config = load_config(bench, cell["config"], root)
     traffic = load_json(os.path.join(root, "bench", "traffic",
                                      f"{cell['traffic']}.json"))
     return cell, config, traffic
@@ -200,9 +257,10 @@ def compare(program: dict, reference: dict) -> tuple:
 
 def reference_leaves(run: Run, point: dict, seed: int,
                      control: bool = False) -> dict:
-    """The plain reference's result for one design point, keyed like the
-    program's leaves for this traffic kind."""
-    import reference
+    """The configuration's plain reference's result for one design point,
+    keyed like the program's leaves for this traffic kind (``run.config``
+    as :func:`load_config` gives it)."""
+    reference = load_reference(run.config["reference"])
     ref = reference.simulate(run.config, interval=point["interval"],
                              read_ratio=point["read_ratio"], seed=seed,
                              n_cycles=int(run.config["n_cycles"]),

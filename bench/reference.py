@@ -136,6 +136,9 @@ class Memory:
         self.read_latency = int(timings["nCL"]) + self.nBL
         self.nREFI = int(timings["nREFI"])
         self.margin = int(config["controller"]["refresh_urgent_margin"])
+        if config["standard"] not in DUAL_BUS:
+            raise ValueError(f"bench/reference.py models DDR5 and HBM3, "
+                             f"not {config['standard']}")
         self.dual = DUAL_BUS[config["standard"]]
         self.kind = np.array([COMMANDS[n][1] for n in NAMES])
         self.col_bus = np.array([False, True, False])

@@ -33,9 +33,12 @@ import glob
 import os
 import re
 
+#: names of collective operations: XLA's, and those that ``shard_map``'s
+#: ``psum``/``pmin``/``pmax`` keep on the TPU (``%psum_invariant.17``,
+#: ``%pmin.17`` in the channel-sharded loop)
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all|"
-    r"psum|send|recv", re.IGNORECASE)
+    r"psum|pmin|pmax|send|recv", re.IGNORECASE)
 TOP = 10
 #: device events at which the TPU profiler stops recording (6 x 2**20;
 #: full-length scalar loops stopped there on a TPU v5e), less 5%

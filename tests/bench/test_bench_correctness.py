@@ -1,17 +1,20 @@
 """The comparison that decides the benchmark's ``correct``, on the CPU at
 sizes a test run can hold.
 
-- The plain reference (``bench/reference.py``) gives every ``Stats`` leaf
-  of the program exactly, on both configurations at several loads.
-- The control (the reference with tRCD one cycle short) fails the
-  comparison.
+- Each configuration's plain reference (``bench/reference.py`` unless its
+  file names another) gives every ``Stats`` leaf of the program exactly,
+  on every configuration at several loads.
+- Its control (the reference with one published guarantee broken;
+  ``bench/reference.py``: tRCD one cycle short) fails the comparison.
 - A whole run of each cell, with the look for a chip skipped, comes out
   correct; with the timed path broken underneath it comes out not
   correct, once for each fault the cell can have: a controller step that
   returns its state unchanged, half of the sweep batch left out (its
   points replaced by copies of the other half), an answer altered where
   it is produced.  The sweep also runs with its batch sharded over four
-  forced host devices, in a child process.
+  forced host devices, in a child process
+  (``test_bench_channel_shard.py``: the scalar run with its channels
+  sharded, and the exchange between the devices left out).
 """
 import json
 import os
@@ -27,7 +30,6 @@ ROOT = os.path.normpath(os.path.join(HERE, "..", ".."))
 sys.path[:0] = [os.path.join(ROOT, "bench")]
 
 import harness  # noqa: E402
-import reference  # noqa: E402
 from devices import stats_leaves  # noqa: E402
 
 #: simulated cycles per call in these tests (the cells run 50,000)
@@ -53,16 +55,23 @@ def _program(config, interval, read_ratio, seed, n):
                                 seed=seed))
 
 
+#: every configuration of the benchmark, each checked by its own reference
+CONFIGS = [c["name"] for c in BENCH["configs"]]
+#: (interval, read ratio, seed) of each configuration's comparison
+LOADS = [(1.0, 0.5, 4_000_000_007), (16.0, 0.667, 11),
+         (1.0, 0.667, 2_147_483_659), (4.0, 1.0, 5)]
+
+
+def _config(name):
+    config = harness.load_config(BENCH, name, ROOT)
+    return config, harness.load_reference(config["reference"])
+
+
 @pytest.mark.parametrize("config_name,interval,read_ratio,seed", [
-    ("ddr5_8ch2r", 1.0, 0.5, 4_000_000_007),
-    ("ddr5_8ch2r", 16.0, 0.667, 11),
-    ("hbm3_16ch", 1.0, 0.667, 2_147_483_659),
-    ("hbm3_16ch", 4.0, 1.0, 5),
-])
+    (c, *load) for c in CONFIGS for load in LOADS])
 def test_reference_gives_every_stats_leaf_of_the_program(
         config_name, interval, read_ratio, seed):
-    config = harness.load_json(os.path.join(
-        ROOT, "bench", "configs", f"{config_name}.json"))
+    config, reference = _config(config_name)
     # long enough for refresh (DDR5 nREFI 9360, staggered over channels)
     n = 3000
     got = _program(config, interval, read_ratio, seed, n)
@@ -72,10 +81,9 @@ def test_reference_gives_every_stats_leaf_of_the_program(
     assert int(ref["cmd_counts"][reference.NAMES.index("REFab")]) > 0
 
 
-@pytest.mark.parametrize("config_name", ["ddr5_8ch2r", "hbm3_16ch"])
+@pytest.mark.parametrize("config_name", CONFIGS)
 def test_control_fails_the_comparison(config_name):
-    config = harness.load_json(os.path.join(
-        ROOT, "bench", "configs", f"{config_name}.json"))
+    config, reference = _config(config_name)
     ref = reference.simulate(config, 1.0, 0.667, 99, N)
     ctl = reference.simulate(config, 1.0, 0.667, 99, N, control=True)
     assert harness.compare(ctl, ref)[0] > 0
@@ -115,6 +123,16 @@ def _plant(monkeypatch, fault):
             s = agg(*a, **k)
             return s._replace(writes_done=s.writes_done + 1)
         monkeypatch.setattr(E, "_aggregate_stats", altered)
+    elif fault == "exchange_left_out":
+        import jax
+        import jax.numpy as jnp
+        psum = jax.lax.psum
+
+        def first_shard_only(x, axis_name):
+            # every shard takes shard 0's part for the sum over the mesh
+            keep = jax.lax.axis_index(axis_name) == 0
+            return psum(jnp.where(keep, x, jnp.zeros_like(x)), axis_name)
+        monkeypatch.setattr(jax.lax, "psum", first_shard_only)
     else:
         assert fault is None
 
@@ -153,7 +171,7 @@ def test_run_is_correct_only_when_the_timed_path_is_sound(
     assert res["metrics"]["sim_cycles_per_s"]["value"] > 0
 
 
-FOUR_CHIP = r"""
+FOUR_DEVICES = r"""
 import json, sys
 sys.path[:0] = [{bench!r}, {src!r}, {tests!r}]
 import jax
@@ -171,27 +189,34 @@ class Patch:
 
 
 out = {{}}
-for fault in (None, "half_batch", "answer_altered"):
+for fault in {faults!r}:
     E.RUN_CACHE.clear()
     patch = Patch()
     _plant(patch, fault)
-    out[str(fault)] = _run_cell("ddr5_8ch2r.sweep24",
-                                jax.devices()[:4])["correct"]
+    out[str(fault)] = _run_cell({cell!r}, jax.devices()[:4])["correct"]
     for obj, name, value in reversed(patch.undo):
         setattr(obj, name, value)
 print(json.dumps(out))
 """
 
 
-def test_sweep_sharded_over_four_host_devices():
+def run_on_four_host_devices(cell: str, faults: tuple) -> dict:
+    """``{str(fault): correct}`` of a whole run of ``cell`` on four forced
+    host devices with each fault planted, in one child process."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    code = FOUR_CHIP.format(bench=os.path.join(ROOT, "bench"),
-                            src=os.path.join(ROOT, "src"), tests=HERE)
+    code = FOUR_DEVICES.format(bench=os.path.join(ROOT, "bench"),
+                               src=os.path.join(ROOT, "src"), tests=HERE,
+                               cell=cell, faults=tuple(faults))
     p = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=900)
     assert p.returncode == 0, p.stderr[-3000:]
-    got = json.loads(p.stdout.strip().splitlines()[-1])
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+def test_sweep_sharded_over_four_host_devices():
+    got = run_on_four_host_devices(
+        "ddr5_8ch2r.sweep24", (None, "half_batch", "answer_altered"))
     assert got == {"None": True, "half_batch": False,
                    "answer_altered": False}
 
@@ -205,7 +230,8 @@ def test_sweep_recovers_probe_latency_sums_exactly():
                                       read_ratios=[0.5]), jax.devices()[:1])
     seed = harness.call_seed(12345, 0)
     (pt,) = runner.call(seed)
-    ref = reference.simulate(config, 2.0, 0.5, seed, 2000)
+    ref = harness.load_reference(config["reference"]).simulate(
+        config, 2.0, 0.5, seed, 2000)
     assert int(pt["probe_cnt"]) > 5
     assert int(pt["probe_lat_sum"]) == int(ref["probe_lat_sum"])
     assert np.array_equal(pt["cmd_counts"], ref["cmd_counts"])

@@ -1,7 +1,7 @@
 """The chip benchmark's harness on the CPU: the command refuses to run
-without a TPU, cells, traffic and metrics are found by name (a cell added
-as files alone is picked up), and the metric arithmetic holds on
-hand-made results."""
+without a TPU, cells, traffic, metrics and references are found by name
+(a cell, or a deployment with its own reference, added as files alone is
+picked up), and the metric arithmetic holds on hand-made results."""
 import json
 import os
 import shutil
@@ -58,8 +58,12 @@ def test_every_cell_resolves_to_its_files(bench):
     for w in bench["workloads"]:
         cell, config, traffic = harness.resolve_cell(bench, w["name"], ROOT)
         assert config["name"] == w["config"] in names
-        assert traffic["kind"] in ("scalar", "sweep")
-        harness.load_plugin("kinds", traffic["kind"], ROOT)
+        kind = harness.load_plugin("kinds", traffic["kind"], ROOT)
+        assert hasattr(kind, "Runner") and hasattr(kind, "CHECKED")
+    for name in names:
+        config = harness.load_config(bench, name, ROOT)
+        ref = harness.load_reference(config["reference"])
+        assert callable(ref.simulate) and len(ref.NAMES) > 0
     for m in bench["per_layer"]:
         assert hasattr(harness.load_plugin("metrics", m["name"], ROOT),
                        "read")
@@ -97,6 +101,86 @@ def test_a_cell_added_as_files_alone_is_picked_up(bench, tmp_path):
     got = harness.read_per_layer(loaded, run, str(root))
     assert got["driver.calls"] == {"value": 1.0, "unit": "calls"}
     assert got["driver.skip_share"]["value"] == pytest.approx(0.8)
+
+
+#: a reference that wraps bench/reference.py and adds 1 to one leaf
+ECHO = '''"""bench/reference.py with one more write served on every run.
+``control=True`` breaks what bench/reference.py's does (tRCD)."""
+import reference
+
+NAMES = reference.NAMES
+
+
+def simulate(config, interval, read_ratio, seed, n_cycles, control=False):
+    out = reference.simulate(config, interval, read_ratio, seed, n_cycles,
+                             control)
+    out["writes_done"] = out["writes_done"] + 1
+    return out
+'''
+
+
+def _deployment_added_as_files(bench, tmp_path, reference):
+    """A checkout with a configuration ``ddr5_echo`` (the DDR5 socket's
+    file, with ``reference`` set where not None) and a scalar cell on it,
+    added as files; returns ``(root, loaded BENCHMARK.json)``."""
+    root = tmp_path / "checkout"
+    shutil.copytree(os.path.join(ROOT, "bench"), root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (root / "bench" / "references").mkdir()
+    (root / "bench" / "references" / "echo.py").write_text(ECHO)
+    config = harness.load_json(os.path.join(ROOT, "bench", "configs",
+                                            "ddr5_8ch2r.json"))
+    config = dict(config, name="ddr5_echo", n_cycles=400)
+    if reference is not None:
+        config["reference"] = reference
+    (root / "bench" / "configs" / "ddr5_echo.json").write_text(
+        json.dumps(config))
+    new = dict(bench)
+    new["configs"] = bench["configs"] + [
+        dict(bench["configs"][0], name="ddr5_echo",
+             file="bench/configs/ddr5_echo.json")]
+    new["workloads"] = bench["workloads"] + [
+        {"name": "ddr5_echo.stream_i1", "config": "ddr5_echo",
+         "traffic": "stream_i1", "chips": 1, "why": "added by files"}]
+    (root / "BENCHMARK.json").write_text(json.dumps(new))
+    return root, harness.load_benchmark(str(root))
+
+
+@pytest.mark.parametrize("reference,differing", [
+    ("bench/references/echo.py", 1), (None, 0)])
+def test_a_deployment_added_as_files_is_checked_by_its_own_reference(
+        bench, tmp_path, reference, differing):
+    import reference as plain
+    root, loaded = _deployment_added_as_files(bench, tmp_path, reference)
+    cell, config, traffic = harness.resolve_cell(
+        loaded, "ddr5_echo.stream_i1", str(root))
+    # the program's result stands in as the plain reference's own
+    seed, n = harness.call_seed(77, 0), int(config["n_cycles"])
+    pt = plain.simulate(config, traffic["interval"], traffic["read_ratio"],
+                        seed, n)
+    pt.update(scan_steps=np.int64(n), skipped_cycles=np.int64(0))
+    run = harness.Run(cell=cell, config=config, traffic=traffic, chips=1,
+                      points=[{"interval": traffic["interval"],
+                               "read_ratio": traffic["read_ratio"]}],
+                      calls=[harness.Call(seed, 0.0, 1.0, [pt])], setup={})
+    checks, failed, _ = harness.check(run, 77, [])
+    assert checks["stats_differing"]["value"] == differing
+    assert failed == bool(differing)
+
+
+def test_a_reference_named_but_missing_is_an_error(bench, tmp_path):
+    root, loaded = _deployment_added_as_files(
+        bench, tmp_path, "bench/references/missing.py")
+    with pytest.raises(harness.BenchError, match="missing.py"):
+        harness.resolve_cell(loaded, "ddr5_echo.stream_i1", str(root))
+
+
+def test_the_plain_reference_refuses_a_standard_it_does_not_model():
+    import reference
+    config = harness.load_json(os.path.join(ROOT, "bench", "configs",
+                                            "ddr5_8ch2r.json"))
+    with pytest.raises(ValueError, match="models DDR5 and HBM3, not LPDDR5"):
+        reference.simulate(dict(config, standard="LPDDR5"), 1.0, 1.0, 1, 10)
 
 
 def test_sim_cycles_per_s_is_all_work_over_all_time():

@@ -43,6 +43,20 @@ def test_reduce_unions_busy_time_per_chip_and_averages():
     assert r["truncated"] is None
 
 
+@pytest.mark.parametrize("name,collective", [
+    ("all-reduce.1", True), ("psum_invariant.17", True), ("pmin.17", True),
+    ("pmax.2", True), ("all-gather.3", True), ("while.70", False),
+    ("add_bitcast_fusion.69", False), ("copy-start.6", False)])
+def test_collectives_are_known_by_the_names_the_tpu_gives_them(
+        name, collective):
+    # chip 0: the named op [0, 40) and a fusion [40, 100)
+    ev = {"devices": {"/device:TPU:0": [(name, 0.0, 40.0),
+                                        ("fusion.1", 40.0, 60.0)]},
+          "host": [("bench.call", 0.0, 100.0)]}
+    r = tracing.reduce(ev, (0.0, 100.0))
+    assert r["collective_share"] == pytest.approx(0.4 if collective else 0)
+
+
 def test_reduce_reports_self_time_and_named_gaps():
     r = tracing.reduce(_events(), (0.0, 200.0))
     ops = dict(r["device_ops"])
