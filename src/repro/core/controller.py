@@ -160,11 +160,13 @@ def pred_act2_exclusive(cspec, ctx):
     ACT-2 candidates may issue (nothing may interrupt it)."""
     if not cspec.split_activation:
         return jnp.ones_like(ctx.cand_cmd, bool)
-    pending = D.pick(ctx.cs.dev.row_state, ctx.bank_hot) == D.ROW_ACTIVATING
-    deadline = D.pick(ctx.cs.dev.act1_clk, ctx.bank_hot) + ctx.dp.nAAD
-    urgent = pending & (ctx.clk + 2 >= deadline)       # slack of one slot
-    is_act2 = ctx.cand_cmd == jnp.int32(cspec.id_ACT2)
-    return jnp.where(jnp.any(urgent), is_act2 & urgent, True)
+    with jax.named_scope("split_act"):
+        pending = D.pick(ctx.cs.dev.row_state,
+                         ctx.bank_hot) == D.ROW_ACTIVATING
+        deadline = D.pick(ctx.cs.dev.act1_clk, ctx.bank_hot) + ctx.dp.nAAD
+        urgent = pending & (ctx.clk + 2 >= deadline)   # slack of one slot
+        is_act2 = ctx.cand_cmd == jnp.int32(cspec.id_ACT2)
+        return jnp.where(jnp.any(urgent), is_act2 & urgent, True)
 
 
 def pred_act2_follows_act1(cspec, ctx):
@@ -172,9 +174,11 @@ def pred_act2_follows_act1(cspec, ctx):
     (the prerequisite decoder guarantees it targets the pending row)."""
     if not cspec.split_activation:
         return jnp.ones_like(ctx.cand_cmd, bool)
-    is_act2 = ctx.cand_cmd == jnp.int32(cspec.id_ACT2)
-    activating = D.pick(ctx.cs.dev.row_state, ctx.bank_hot) == D.ROW_ACTIVATING
-    return ~is_act2 | activating
+    with jax.named_scope("split_act"):
+        is_act2 = ctx.cand_cmd == jnp.int32(cspec.id_ACT2)
+        activating = D.pick(ctx.cs.dev.row_state,
+                            ctx.bank_hot) == D.ROW_ACTIVATING
+        return ~is_act2 | activating
 
 
 def _bh_hashes(bank, row):
@@ -535,8 +539,10 @@ def channel_horizon(cspec: CompiledSpec, dp: D.DynParams,
         ref_at = _refresh_ready_at(cspec, table, _refresh_cmd(cspec, cs.dev))
         h = jnp.minimum(h, jnp.min(jnp.maximum(due_t, ref_at)))
     if cspec.data_clock_sync:
-        cu = cs.dev.clock_until
-        h = jnp.minimum(h, jnp.min(jnp.where(cu > clk, cu, HORIZON_MAX)))
+        with jax.named_scope("clock_sync"):
+            cu = cs.dev.clock_until
+            h = jnp.minimum(h, jnp.min(jnp.where(cu > clk, cu,
+                                                 HORIZON_MAX)))
     if cfg.blockhammer_threshold:
         h = jnp.minimum(h, ((clk + dp.nREFI - jnp.int32(1)) // dp.nREFI)
                         * dp.nREFI)

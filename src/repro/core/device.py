@@ -313,32 +313,33 @@ def prereq(cspec: CompiledSpec, dp: DynParams, state: DeviceState,
     rs = pick(state.row_state, bank_hot)
     open_hit = rs == row
     closed = rs == ROW_CLOSED
-    activating = rs == ROW_ACTIVATING
 
     final = jnp.where(is_write, jnp.int32(cspec.id_WR), jnp.int32(cspec.id_RD))
     col_cmd = final
     if cspec.data_clock_sync:
-        ru_hot = onehot(refresh_unit(cspec, addr_sub), cspec.n_refresh_units)
-        clock_on = clk < pick(state.clock_until, ru_hot)
-        sync = jnp.where(is_write,
-                         jnp.int32(cspec.id_CAS_WR if cspec.id_CAS_WR >= 0 else cspec.id_RCKSTRT),
-                         jnp.int32(cspec.id_CAS_RD if cspec.id_CAS_RD >= 0 else cspec.id_RCKSTRT))
-        col_cmd = jnp.where(clock_on, final, sync)
+        with jax.named_scope("clock_sync"):
+            ru_hot = onehot(refresh_unit(cspec, addr_sub),
+                            cspec.n_refresh_units)
+            clock_on = clk < pick(state.clock_until, ru_hot)
+            sync = jnp.where(is_write,
+                             jnp.int32(cspec.id_CAS_WR if cspec.id_CAS_WR >= 0 else cspec.id_RCKSTRT),
+                             jnp.int32(cspec.id_CAS_RD if cspec.id_CAS_RD >= 0 else cspec.id_RCKSTRT))
+            col_cmd = jnp.where(clock_on, final, sync)
 
+    cmd_row = row
     if cspec.split_activation:
-        opener = jnp.int32(cspec.id_ACT1)
-        cmd = jnp.where(closed, opener,
-              jnp.where(activating, jnp.int32(cspec.id_ACT2),
-              jnp.where(open_hit, col_cmd, jnp.int32(cspec.id_PRE))))
+        with jax.named_scope("split_act"):
+            activating = rs == ROW_ACTIVATING
+            opener = jnp.int32(cspec.id_ACT1)
+            cmd = jnp.where(closed, opener,
+                  jnp.where(activating, jnp.int32(cspec.id_ACT2),
+                  jnp.where(open_hit, col_cmd, jnp.int32(cspec.id_PRE))))
+            cmd_row = jnp.where(cmd == jnp.int32(cspec.id_ACT2),
+                                pick(state.act1_row, bank_hot), row)
     else:
         opener = jnp.int32(cspec.id_ACT)
         cmd = jnp.where(closed, opener,
               jnp.where(open_hit, col_cmd, jnp.int32(cspec.id_PRE)))
-
-    cmd_row = row
-    if cspec.split_activation:
-        cmd_row = jnp.where(cmd == jnp.int32(cspec.id_ACT2),
-                            pick(state.act1_row, bank_hot), row)
     return cmd, cmd_row, open_hit
 
 
@@ -408,12 +409,14 @@ def issue(cspec: CompiledSpec, dp: DynParams, state: DeviceState,
     a1c = jnp.where(a1_hit, clk, state.act1_clk)
 
     cu = state.clock_until
-    cu = jnp.where(has(S.FX_CLOCK_ON) & ru_hit, clk + dp.clock_idle, cu)
-    # data transfer keeps the data clock alive
-    is_data = has(S.FX_FINAL_RD) | has(S.FX_FINAL_WR)
     if cspec.data_clock_sync:
-        cu = jnp.where(is_data & ru_hit,
-                       jnp.maximum(cu, clk + dp.clock_idle), cu)
+        with jax.named_scope("clock_sync"):
+            cu = jnp.where(has(S.FX_CLOCK_ON) & ru_hit,
+                           clk + dp.clock_idle, cu)
+            # data transfer keeps the data clock alive
+            is_data = has(S.FX_FINAL_RD) | has(S.FX_FINAL_WR)
+            cu = jnp.where(is_data & ru_hit,
+                           jnp.maximum(cu, clk + dp.clock_idle), cu)
 
     lr = state.last_ref
     lr = jnp.where((cmd == jnp.int32(cspec.id_REFab)) & enable & ru_hit,

@@ -835,9 +835,15 @@ def _aggregate_stats(msys: MemorySystemSpec, chs: list, clk,
 #: vmapped ``controller_step``), ``fold`` (stats fold, fused reduction
 #: and its ``psum``), ``horizon`` (fast-forward horizon, ``pmin`` and
 #: idle jump), ``trace_write`` (trace-buffer update) and
-#: ``telemetry_snap`` (window snapshot).
+#: ``telemetry_snap`` (window snapshot).  Two nested parts name a
+#: standard's own mechanisms inside ``controller`` and ``horizon``, and
+#: only in the programs of standards that have them: ``split_act``
+#: (LPDDR5/6 ACT-1/ACT-2: the ACT-2 predicates, the Activating branch of
+#: the prerequisite decode and its ``act1_row`` read) and ``clock_sync``
+#: (WCK/RCK: the ``clock_until`` read and sync choice of the decode, the
+#: clock update at issue, the horizon's clock-expiry term).
 SCOPES = ("frontend", "controller", "fold", "horizon", "trace_write",
-          "telemetry_snap")
+          "telemetry_snap", "split_act", "clock_sync")
 
 
 def make_run(spec, ccfg: C.ControllerConfig,

@@ -16,9 +16,9 @@ class LPDDR5(DRAMSpec):
     timing_params = base_timing_params(extra=(
         "nAAD", "nAAD_MIN", "nWCKEN", "nWCKIDLE"))
     timing_constraints = base_constraints(act="ACT2") + [
-        # WCK sync commands must lead the column access by nWCKEN
-        TimingConstraint("rank", ["CAS_RD"], ["RD"], "nWCKEN"),
-        TimingConstraint("rank", ["CAS_WR"], ["WR"], "nWCKEN"),
+        # a CAS starts the rank's WCK, which every column access then
+        # needs synchronised: nWCKEN from either CAS to any RD/WR
+        TimingConstraint("rank", ["CAS_RD", "CAS_WR"], ["RD", "WR"], "nWCKEN"),
         TimingConstraint("rank", ["CAS_RD", "CAS_WR"], ["CAS_RD", "CAS_WR"], "nWCKEN"),
     ]
     org_presets = {

@@ -95,6 +95,23 @@ class TestDataClockSync:
         idle = t2 + dut.cspec.clock_idle + 1
         assert dut.probe("RD", addr, clk=idle).preq == "CAS_RD"
 
+    def test_either_cas_holds_both_column_commands_until_the_sync(self):
+        """A WR may not ride a clock that a CAS_RD started fewer than
+        nWCKEN cycles ago: the sync is the rank's, not the command's."""
+        dut = DeviceUnderTest("LPDDR5", "LPDDR5_8Gb_x16", "LPDDR5_6400")
+        a = dut.addr_vec(Rank=0, BankGroup=0, Bank=0, Row=4, Column=0)
+        b = dut.addr_vec(Rank=0, BankGroup=1, Bank=0, Row=9, Column=0)
+        dut.issue("ACT1", a, clk=0)
+        dut.issue("ACT2", a, clk=2)
+        dut.issue("ACT1", b, clk=4)
+        dut.issue("ACT2", b, clk=6)
+        t = 6 + dut.timings["nRCD"]
+        dut.issue("CAS_RD", a, clk=t)
+        sync = t + dut.timings["nWCKEN"]
+        early = dut.probe("WR", b, clk=sync - 1)
+        assert early.preq == "WR" and early.timing_OK is False
+        assert dut.probe("WR", b, clk=sync).timing_OK
+
     def test_rck_for_gddr7(self):
         dut = DeviceUnderTest("GDDR7", "GDDR7_16Gb_x32", "GDDR7_32")
         addr = dut.addr_vec(Rank=0, BankGroup=0, Bank=0, Row=4, Column=0)

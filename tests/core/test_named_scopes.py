@@ -152,3 +152,49 @@ def _results(case):
 @pytest.mark.parametrize("case", sorted(BEFORE_SCOPES))
 def test_results_are_bit_identical_to_the_program_without_scopes(case):
     assert _sha(_results(case)) == BEFORE_SCOPES[case]
+
+
+#: a standard's own mechanisms, named inside the loop's parts
+MECHANISM_SCOPES = {"split_act", "clock_sync"}
+
+
+def _mechanism_scopes(standard, org, timing) -> dict:
+    """``{loop part: mechanism scopes under it}`` in the op names of a
+    2-channel scalar program of ``standard``, compiled on the host."""
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.core import Simulator
+    from repro.core import engine as E
+    sim = Simulator(standard, org, timing, channels=2, channel_shard=False)
+    fn = E.RunCache().get(sim.cspec, sim.controller, sim.frontend, 300)
+    text = fn.fn.lower(sim._dyn_params(), sim.frontend.params(),
+                       jnp.uint32(1)).compile().as_text()
+    found = {}
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        # a scope entered under vmap reads "vmap(<scope>)"
+        parts = [re.sub(r"^vmap\((.*)\)$", r"\1", p)
+                 for p in path.split("/")]
+        if "body" not in parts:
+            continue
+        loop = [p for p in parts if p in LOOP_SCOPES]
+        nested = set(parts) & MECHANISM_SCOPES
+        if loop and nested:
+            found.setdefault(loop[0], set()).update(nested)
+    return found
+
+
+@pytest.mark.parametrize("standard,org,timing,want", [
+    ("LPDDR5", "LPDDR5_8Gb_x16_2R", "LPDDR5_6400",
+     {"controller": MECHANISM_SCOPES, "horizon": MECHANISM_SCOPES}),
+    ("DDR5", "DDR5_16Gb_x8_2R", "DDR5_4800B", {}),
+    ("HBM3", "HBM3_16Gb", "HBM3_5200", {}),
+])
+def test_a_standards_own_mechanisms_are_scoped_in_its_program_only(
+        standard, org, timing, want):
+    # split activation and WCK sync reach LPDDR5's compiled op names,
+    # inside the controller and (the prerequisite decode and the clock
+    # expiry term of the fast-forward horizon) the horizon; standards
+    # without them carry neither name
+    assert _mechanism_scopes(standard, org, timing) == want
