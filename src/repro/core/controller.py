@@ -260,6 +260,13 @@ class StepEvents(NamedTuple):
     (-1 for refresh-engine commands); ``hit_ready`` records whether a
     post-predicate row-hit candidate existed when this bus slot selected —
     the observable the FR-FCFS row-hit-first audit replays.
+
+    ``slot_ready_at`` and ``ref_ready_at`` are what the (last) selection
+    pass looked up before it chose: each queue slot's earliest issue clock
+    of its prerequisite command, and each refresh unit's of its refresh
+    command.  On a cycle that issued nothing they still hold for the
+    post-cycle state, and the engine's fast-forward horizon reads them
+    (:func:`idle_horizon`).
     """
     cmd: jnp.ndarray          # (2,) issued command per bus slot [col, row]
     bank: jnp.ndarray         # (2,)
@@ -272,6 +279,8 @@ class StepEvents(NamedTuple):
     probe_latency: jnp.ndarray    # i32 completion - arrival (valid if probe)
     probe_completion: jnp.ndarray  # i32 absolute completion clock
     deferred: jnp.ndarray         # i32 candidates masked by predicates
+    slot_ready_at: jnp.ndarray    # (Q,) earliest issue clock per slot
+    ref_ready_at: jnp.ndarray     # (n_refresh_units,) same, refresh cmd
 
 
 # --------------------------------------------------------------------------
@@ -280,15 +289,17 @@ class StepEvents(NamedTuple):
 
 
 def _candidates(cspec, dp, cs, clk, bank_hot):
+    """Per queue slot: the prerequisite command, its row, whether the
+    request's row is open, and the command's earliest issue clock; plus
+    the dense table that clock was read from."""
     q = cs.queue
     cand_cmd, cand_row, open_hit = D.prereq(cspec, dp, cs.dev, q.is_write,
                                             q.sub, q.row, clk)
     # dense (n_cmds, n_banks) earliest table, read per slot by a one-hot
     # select (cand_cmd is one of D.prereq_cmds; the bank is in range)
     table = D.earliest_ready_table(cspec, dp, cs.dev)
-    timing_ready = clk >= D.table_at(table, cand_cmd, bank_hot,
-                                     D.prereq_cmds(cspec))
-    return cand_cmd, cand_row, open_hit, timing_ready, table
+    t_slot = D.table_at(table, cand_cmd, bank_hot, D.prereq_cmds(cspec))
+    return cand_cmd, cand_row, open_hit, t_slot, table
 
 
 def _refresh_plan(cspec, dp, cs, clk, cfg: ControllerConfig):
@@ -340,14 +351,14 @@ def _ru_addr(cspec, ru):
 
 
 def _try_issue_refresh(cspec, dp, cs, clk, due, urgent, ref_cmd,
-                       cmd_ok, table):
+                       cmd_ok, ref_at):
     """Issue the refresh-engine command of the most-overdue due unit.
 
     Refresh is *opportunistic* until urgent: a merely-due refresh yields to
     pending requests targeting the same unit; an urgent one preempts (the
     ``refresh_urgency`` predicate blocks those requests at the same time).
-    ``table`` is the pass's dense earliest-issue table; the refresh unit's
-    representative bank resolves its timing through it.  ``cmd_ok`` is the
+    ``ref_at`` is each unit's earliest issue clock of ``ref_cmd``
+    (:func:`_refresh_ready_at` of the pass's table).  ``cmd_ok`` is the
     pass's static per-command bus mask.  ``ru`` is an argmax over the
     refresh units, so its one-hot reads are in range.
     """
@@ -357,7 +368,7 @@ def _try_issue_refresh(cspec, dp, cs, clk, due, urgent, ref_cmd,
     cmd = D.pick(ref_cmd, ru_hot)
     sub = _ru_addr(cspec, ru)
     ok_kind = D.lut(cmd_ok, cmd)
-    ready = clk >= D.pick(_refresh_ready_at(cspec, table, ref_cmd), ru_hot)
+    ready = clk >= D.pick(ref_at, ru_hot)
     q = cs.queue
     pending_here = jnp.any(q.valid & (q.sub[:, 0] == ru))
     may_go = D.pick(urgent, ru_hot) | ~pending_here
@@ -387,10 +398,12 @@ def _select_and_issue(cspec, dp, cs, clk, cfg, preds, kind_ok, sched_fn,
     bank = D.flat_bank(cspec, q.sub)
     ru = D.refresh_unit(cspec, q.sub)
     bank_hot = D.onehot(bank, cspec.n_banks)
-    cand_cmd, cand_row, open_hit, timing_ready, table = _candidates(
+    cand_cmd, cand_row, open_hit, t_slot, table = _candidates(
         cspec, dp, cs, clk, bank_hot)
+    timing_ready = clk >= t_slot
 
     due, urgent, ref_cmd = _refresh_plan(cspec, dp, cs, clk, cfg)
+    ref_at = _refresh_ready_at(cspec, table, ref_cmd)
     ctx = PredCtx(dp=dp, cs=cs, clk=clk, cand_cmd=cand_cmd,
                   cand_row=cand_row, open_hit=open_hit, bank=bank, ru=ru,
                   ref_urgent=urgent, bank_hot=bank_hot,
@@ -413,7 +426,7 @@ def _select_and_issue(cspec, dp, cs, clk, cfg, preds, kind_ok, sched_fn,
 
     # refresh engine first (its commands obey the same kind restriction)
     cs, ref_issued, ref_cmd_done, ref_bank = _try_issue_refresh(
-        cspec, dp, cs, clk, due, urgent, ref_cmd, cmd_ok, table)
+        cspec, dp, cs, clk, due, urgent, ref_cmd, cmd_ok, ref_at)
 
     hit_ready = jnp.any(mask & open_hit) & ~ref_issued
     slot, ok = sched_fn(mask & ~ref_issued, open_hit, q.arrive)
@@ -473,6 +486,7 @@ def _select_and_issue(cspec, dp, cs, clk, cfg, preds, kind_ok, sched_fn,
         probe_latency=jnp.where(probe, completion - arrive, 0),
         probe_completion=jnp.where(probe, completion, 0),
         deferred=deferred,
+        slot_ready_at=t_slot, ref_ready_at=ref_at,
     )
     cs = cs._replace(dev=dev, queue=q._replace(valid=valid),
                      hit_streak=streak, bh_sketch=sk, prac_count=prac)
@@ -492,31 +506,31 @@ def channel_horizon(cspec: CompiledSpec, dp: D.DynParams,
                     link_latency: int = 0):
     """Earliest cycle ``>= clk`` at which THIS channel could issue any
     command — queue candidate or refresh engine — evaluated on the
-    current (post-cycle) state.
+    current (post-cycle) state: the selection pass's lookup, made anew
+    at ``clk``, reduced by :func:`idle_horizon`.
 
     CONSERVATIVE by construction: predicate, bus-kind, and scheduler
     masks are ignored (they only *shrink* the issue set, so ignoring
     them can only move the horizon earlier), and an early horizon merely
     executes an idle cycle.  What it must never do is overshoot, and it
     can't: every issue requires ``pre_pred`` (valid & timing-ready [&
-    link-visible]) or a due+ready refresh unit, and both bounds below
-    are exact lower bounds on those events.  Between ``clk`` and the
+    link-visible]) or a due+ready refresh unit, and both bounds are
+    exact lower bounds on those events.  Between ``clk`` and the
     horizon the channel state is frozen (every controller/device update
     is gated on an issue), so the bound needs no re-evaluation until the
-    next executed cycle.  Components:
+    next executed cycle.
 
-    * queue: per valid slot, the dense last-issue/ring earliest-ready
-      table at the slot's prerequisite command (the same
-      ``table[cand_cmd, bank]`` lookup the selection pipeline performs);
-      candidates cannot flip while idle except via WCK/RCK clock expiry
-      — bounded separately below;
-    * refresh: per unit, ``max(due clock, earliest-ready of its
-      PREab/REFab candidate)``; a PRAC alert makes the unit due NOW;
-    * clock expiry (``data_clock_sync`` standards): the first
-      ``clock_until`` still in the future, where a column candidate
-      flips between RD/WR and its CAS/RCKSTRT sync prerequisite;
-    * BlockHammer sketch decay: the next ``nREFI`` multiple (the sketch
-      halves on those cycles, so they must be executed, not skipped).
+    The engine computes the horizon only after a cycle that accepted
+    and issued nothing.  The state it reads is then the one the
+    controller's pass has just read, so the engine hands that pass's
+    lookup (``StepEvents.slot_ready_at`` / ``ref_ready_at``) to
+    :func:`idle_horizon` instead of calling this function
+    (:func:`lookup_outlives_idle_cycle`).  Standards with WCK/RCK data
+    clock sync are the exception: their prerequisite decode reads the
+    clock, and a rank's data clock that stops at ``clk`` flips its
+    column candidate between the pass's cycle and this one, so they
+    recompute here.  This function stays the reference the tests hold
+    the engine's reuse to.
     """
     q = cs.queue
     bank_hot = D.onehot(D.flat_bank(cspec, q.sub), cspec.n_banks)
@@ -524,6 +538,33 @@ def channel_horizon(cspec: CompiledSpec, dp: D.DynParams,
                               clk)
     table = D.earliest_ready_table(cspec, dp, cs.dev)
     t_slot = D.table_at(table, cand_cmd, bank_hot, D.prereq_cmds(cspec))
+    ref_at = _refresh_ready_at(cspec, table, _refresh_cmd(cspec, cs.dev))
+    return idle_horizon(cspec, dp, cfg, cs, clk, t_slot, ref_at,
+                        link_latency)
+
+
+def idle_horizon(cspec: CompiledSpec, dp: D.DynParams,
+                 cfg: ControllerConfig, cs: CtrlState, clk, t_slot, ref_at,
+                 link_latency: int = 0):
+    """:func:`channel_horizon` from a lookup already made: ``t_slot``,
+    each queue slot's earliest issue clock of its prerequisite command
+    (``table[cand_cmd, bank]``, the lookup the selection pipeline
+    performs), and ``ref_at``, each refresh unit's of its PREab/REFab
+    (:func:`_refresh_ready_at`).  Components:
+
+    * queue: the minimum of ``t_slot`` over valid slots, floored at
+      ``arrive + link_latency`` for a CXL link; candidates cannot flip
+      while idle except via WCK/RCK clock expiry — bounded separately
+      below;
+    * refresh: per unit, ``max(due clock, ref_at)``; a PRAC alert makes
+      the unit due NOW;
+    * clock expiry (``data_clock_sync`` standards): the first
+      ``clock_until`` still in the future, where a column candidate
+      flips between RD/WR and its CAS/RCKSTRT sync prerequisite;
+    * BlockHammer sketch decay: the next ``nREFI`` multiple (the sketch
+      halves on those cycles, so they must be executed, not skipped).
+    """
+    q = cs.queue
     if link_latency:
         t_slot = jnp.maximum(t_slot, q.arrive + jnp.int32(link_latency))
     h = jnp.min(jnp.where(q.valid, t_slot, HORIZON_MAX),
@@ -536,7 +577,6 @@ def channel_horizon(cspec: CompiledSpec, dp: D.DynParams,
                 (cs.prac_count >= cfg.prac_threshold).reshape(
                     cspec.n_refresh_units, banks_per_ru), axis=1)
             due_t = jnp.where(alert, clk, due_t)
-        ref_at = _refresh_ready_at(cspec, table, _refresh_cmd(cspec, cs.dev))
         h = jnp.minimum(h, jnp.min(jnp.maximum(due_t, ref_at)))
     if cspec.data_clock_sync:
         with jax.named_scope("clock_sync"):
@@ -549,6 +589,15 @@ def channel_horizon(cspec: CompiledSpec, dp: D.DynParams,
     return jnp.maximum(h, clk)
 
 
+def lookup_outlives_idle_cycle(cspec: CompiledSpec) -> bool:
+    """Whether a selection pass's lookup at cycle ``t`` is still exact
+    on the post-cycle state at ``t + 1`` when the cycle issued nothing:
+    the earliest-issue table depends on the device state alone, and the
+    prerequisite decode reads the clock only for data-clock-sync
+    standards (a rank's WCK/RCK may stop in between)."""
+    return not cspec.data_clock_sync
+
+
 _IDLE_SLOT = dict(cmd=jnp.int32(-1), bank=jnp.int32(-1), row=jnp.int32(-1),
                   arrive=jnp.int32(-1), hit_ready=False)
 
@@ -559,13 +608,17 @@ def _pack_events(ev_col: dict, ev_row: dict | None = None) -> StepEvents:
     Per-bus-slot fields stack [col-bus, row-bus] (the row slot is idle for
     single-bus standards); per-cycle outcome fields OR/sum across passes —
     at most one pass can serve a given request, so the sums are exact.
+    The lookups are the last pass's (on a cycle that issued nothing both
+    passes saw the same state).
     """
     if ev_row is None:
         ev_row = dict(_IDLE_SLOT,
                       **{k: jnp.zeros_like(ev_col[k])
                          for k in ("served_read", "served_write",
                                    "served_probe", "probe_latency",
-                                   "probe_completion", "deferred")})
+                                   "probe_completion", "deferred")},
+                      slot_ready_at=ev_col["slot_ready_at"],
+                      ref_ready_at=ev_col["ref_ready_at"])
     slot = {k: jnp.stack([jnp.asarray(ev_col[k]), jnp.asarray(ev_row[k])])
             for k in ("cmd", "bank", "row", "arrive", "hit_ready")}
     return StepEvents(
@@ -577,6 +630,8 @@ def _pack_events(ev_col: dict, ev_row: dict | None = None) -> StepEvents:
         probe_completion=(ev_col["probe_completion"]
                           + ev_row["probe_completion"]),
         deferred=ev_col["deferred"] + ev_row["deferred"],
+        slot_ready_at=ev_row["slot_ready_at"],
+        ref_ready_at=ev_row["ref_ready_at"],
     )
 
 

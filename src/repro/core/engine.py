@@ -958,7 +958,8 @@ def make_run(spec, ccfg: C.ControllerConfig,
         # widens it to 6 with the cycle's issued command count and
         # returns it, so the macro-stepper can gate its horizon
         # computation on a busy/idle verdict that is uniform across
-        # shards by construction (it rides the psum).
+        # shards by construction (it rides the psum); it also returns
+        # the groups' events, whose lookups the idle horizon reads.
         # each part runs in its named scope (SCOPES)
         with jax.named_scope("frontend"):
             queues, draft = F.system_frontend_insert(
@@ -1001,7 +1002,7 @@ def make_run(spec, ccfg: C.ControllerConfig,
         ys = tuple(TraceArrays(e.cmd, e.bank, e.row, e.arrive,
                                e.hit_ready) for e in evs) if trace else None
         if fast_forward:
-            return out, ys, loc
+            return out, ys, loc, tuple(evs)
         return out, ys
 
     def _finalize_trace(ys_groups):
@@ -1120,18 +1121,30 @@ def make_run(spec, ccfg: C.ControllerConfig,
     _a_cyc, _c_cyc = F.lcg_affine(_k_draws)
     _paced = F.paced_by_arrive(fcfg, rp)
 
-    def _horizon(sim, dps, fp):
+    def _horizon(sim, dps, fp, evs):
         """min over all event sources of the next cycle >= sim.clk at
         which anything could happen: frontend arrival/probe attempts
         plus every channel's controller horizon.  Conservative — an
         early horizon just executes an idle cycle (see
-        ``C.channel_horizon``)."""
+        ``C.channel_horizon``).  ``sim`` follows a cycle that accepted
+        and issued nothing, so each channel's state is the one its
+        controller pass just read: a group whose lookup outlives that
+        cycle reduces the pass's own (``evs``, ``C.idle_horizon``); a
+        data-clock-sync group looks up anew at ``sim.clk``."""
         h = F.arrival_horizon(fcfg, fp, sim.fs, sim.clk, rp, _paced)
         for gi, (grp, dp) in enumerate(zip(groups, dps)):
-            hc = jax.vmap(
-                lambda s: C.channel_horizon(grp.cspec, dp, ccfg, s,
-                                            sim.clk, grp.link_latency)
-            )(sim.gs[gi].cs)
+            if C.lookup_outlives_idle_cycle(grp.cspec):
+                ev = evs[gi]
+                hc = jax.vmap(
+                    lambda s, t, r: C.idle_horizon(
+                        grp.cspec, dp, ccfg, s, sim.clk, t, r,
+                        grp.link_latency)
+                )(sim.gs[gi].cs, ev.slot_ready_at, ev.ref_ready_at)
+            else:
+                hc = jax.vmap(
+                    lambda s: C.channel_horizon(grp.cspec, dp, ccfg, s,
+                                                sim.clk, grp.link_latency)
+                )(sim.gs[gi].cs)
             h = jnp.minimum(h, jnp.min(hc))
         return h
 
@@ -1197,7 +1210,7 @@ def make_run(spec, ccfg: C.ControllerConfig,
         def step(c):
             sim, steps, bufs, snaps = c
             t0 = sim.clk
-            out, ys, loc = body(sim)
+            out, ys, loc, evs = body(sim)
             if trace:
                 z = jnp.int32(0)
                 with jax.named_scope("trace_write"):
@@ -1217,7 +1230,8 @@ def make_run(spec, ccfg: C.ControllerConfig,
                     # uniform again
                     nxt_clk = _vary(nxt_clk, axis_name)
                 h = jax.lax.cond(busy, lambda _: nxt_clk,
-                                 lambda _: _horizon(out, dps, fp), None)
+                                 lambda _: _horizon(out, dps, fp, evs),
+                                 None)
                 if axis_name is not None:
                     h = jax.lax.pmin(h, axis_name)
                 cap = jnp.int32(n_cycles)

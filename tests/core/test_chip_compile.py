@@ -161,18 +161,79 @@ def _count_prims(jaxpr, name):
     return n
 
 
-@pytest.mark.parametrize("part", ["controller_step", "channel_horizon"])
+def _idle_horizon(cspec, dp, ccfg, cs, clk):
+    """``idle_horizon`` fed with a lookup of the pass's shapes."""
+    q = jnp.zeros(cs.queue.valid.shape, jnp.int32) + clk
+    ru = jnp.zeros((cspec.n_refresh_units,), jnp.int32) + clk
+    return C.idle_horizon(cspec, dp, ccfg, cs, clk, q, ru)
+
+
+PARTS = {"controller_step": C.controller_step,
+         "channel_horizon": C.channel_horizon,
+         "idle_horizon": _idle_horizon}
+
+
+@pytest.mark.parametrize("part", sorted(PARTS))
 @pytest.mark.parametrize("std", sorted(GATHER_FREE))
 def test_controller_and_horizon_jaxpr_have_no_gather(std, part):
     """The jaxpr twin of the described-chip check, for the tier-1 run
-    where no TPU compiler is installed: ``controller_step`` and
-    ``channel_horizon`` vmapped over 4 channels trace no gather."""
+    where no TPU compiler is installed: ``controller_step``,
+    ``channel_horizon`` and ``idle_horizon`` vmapped over 4 channels
+    trace no gather."""
     cspec = compile_spec(*GATHER_FREE[std], channels=4)
     ccfg = ControllerConfig()
     dp = D.dyn_params(cspec)
     cs = jax.vmap(lambda _: C.init_ctrl_state(cspec, ccfg.queue_depth))(
         jnp.arange(4))
-    fn = getattr(C, part)
+    fn = PARTS[part]
     jx = jax.make_jaxpr(jax.vmap(
         lambda s: fn(cspec, dp, ccfg, s, jnp.int32(100))))(cs)
     assert _count_prims(jx.jaxpr, "gather") == 0
+
+
+#: the per-slot lookup's two parts, named in a test-only scope each
+LOOKUP_PARTS = ("table_at", "earliest_ready_table")
+
+
+def _lookup_scopes(monkeypatch, triple) -> dict:
+    """``{loop part: lookup parts under it}`` in the op names of a
+    2-point vmapped fast-forward program of a 2-channel ``triple``,
+    compiled on the host, with ``D.table_at`` and
+    ``D.earliest_ready_table`` each run under a scope of its name."""
+    for name in LOOKUP_PARTS:
+        fn = getattr(D, name)
+
+        def scoped(*a, _fn=fn, _name=name, **k):
+            with jax.named_scope(_name):
+                return _fn(*a, **k)
+        monkeypatch.setattr(D, name, scoped)
+    cspec = compile_spec(*triple, channels=2)
+    fcfg = FrontendConfig()
+    fp = F.stack_params([(16.0, 0.7), (1.0, 0.7)], fcfg.probe_gap)
+    fn = jax.vmap(E.make_run(cspec, ControllerConfig(), fcfg, 300,
+                             trace=False), in_axes=(None, 0, None))
+    text = jax.jit(fn).lower(*_args(cspec, fp)).compile().as_text()
+    found = {}
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        # a scope entered under vmap reads "vmap(<scope>)"
+        parts = [re.sub(r"^vmap\((.*)\)$", r"\1", p)
+                 for p in path.split("/")]
+        loop = [p for p in parts if p in ("controller", "horizon")]
+        if "body" in parts and loop:
+            found.setdefault(loop[0], set()).update(
+                set(parts) & set(LOOKUP_PARTS))
+    return found
+
+
+@pytest.mark.parametrize("triple,horizon", [
+    (DDR5_2R, set()),
+    (("LPDDR5", "LPDDR5_8Gb_x16_2R", "LPDDR5_6400"), set(LOOKUP_PARTS)),
+])
+def test_horizon_reads_the_controllers_lookup_unless_the_clock_decides(
+        monkeypatch, triple, horizon):
+    """DDR5's fast-forward horizon builds no earliest-ready table and
+    reads none per slot: it reduces the controller's lookup.  LPDDR5,
+    whose prerequisite decode reads the clock, looks up anew there."""
+    found = _lookup_scopes(monkeypatch, triple)
+    assert found["controller"] == set(LOOKUP_PARTS)
+    assert found["horizon"] == horizon

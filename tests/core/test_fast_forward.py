@@ -12,6 +12,11 @@ Two complementary oracles pin the fast-forward engine:
   every cycle below ``channel_horizon`` must be issue-incapable per the
   oracle's own ``earliest``/``prereq`` semantics (queue candidates and
   the refresh engine both).
+
+The engine's horizon reduces the controller pass's own lookup after an
+idle cycle (``idle_horizon``) where that lookup outlives the cycle, and
+``channel_horizon`` recomputes it otherwise: both forms are held to each
+other here, state by state and run by run.
 """
 import dataclasses
 
@@ -53,6 +58,7 @@ from repro.core import (ControllerConfig, DeviceUnderTest, FrontendConfig,
                         Simulator, compile_spec, compile_system)
 from repro.core import controller as C
 from repro.core import device as D
+from repro.core import engine as E
 from repro.dse.spec import DEFAULT_SYSTEMS
 from repro.trace import capture, to_replay
 
@@ -232,6 +238,50 @@ def test_random_bursty_paced_replay_ff_equality(seed, deps, src_interval):
     _check_bursty_paced_replay_ff_equality(seed, deps, src_interval)
 
 
+@pytest.fixture
+def recomputing_horizon(monkeypatch):
+    """Run ``fn()`` with every group's horizon looked up anew on the
+    post-cycle state (``channel_horizon``), in programs traced for it."""
+    def run(fn):
+        E.RUN_CACHE.clear()
+        with monkeypatch.context() as m:
+            m.setattr(C, "lookup_outlives_idle_cycle", lambda cspec: False)
+            out = fn()
+        E.RUN_CACHE.clear()
+        return out
+    return run
+
+
+@pytest.mark.parametrize("standard,org,timing", [
+    ("DDR5", "DDR5_16Gb_x8_2R", "DDR5_4800B"),
+    ("HBM3", "HBM3_16Gb", "HBM3_5200"),            # two command buses
+    ("LPDDR5", "LPDDR5_8Gb_x16_2R", "LPDDR5_6400"),  # keeps the recompute
+])
+def test_ff_steps_match_the_recomputing_horizon(standard, org, timing,
+                                                recomputing_horizon):
+    """The horizon read from the controller's lookup is the recomputed
+    one: every ``Stats`` leaf, ``scan_steps`` and ``skipped_cycles``
+    included, is identical, on the scalar and the vmapped paths; and the
+    per-cycle engine agrees on everything but the step accounting."""
+    sim = Simulator(standard, org, timing, channels=2, channel_shard=False)
+
+    def runs():
+        return ([sim.run(3000, interval=i, read_ratio=0.667, seed=7)
+                 for i in (16.0, 4.0)],
+                sim.run_batch(2000, (64.0, 8.0, 1.0), (1.0, 0.5), seed=9)[1])
+
+    scalar, batch = runs()
+    want_scalar, want_batch = recomputing_horizon(runs)
+    for got, want in zip(scalar, want_scalar):
+        assert got.to_dict() == want.to_dict()
+        assert int(got.skipped_cycles) > 0
+    _trees_equal(batch, want_batch)
+    assert int(np.sum(batch.skipped_cycles)) > 0
+    off = sim.run(3000, interval=16.0, read_ratio=0.667, seed=7,
+                  fast_forward=False)
+    assert _strip(scalar[0]) == _strip(off)
+
+
 # ---------------------------------------------------------------------------
 # horizon safety vs the scalar DeviceUnderTest oracle
 # ---------------------------------------------------------------------------
@@ -369,3 +419,99 @@ def test_horizon_conservative_not_stuck(seed):
 @given(seed=st.integers(0, 2**31 - 1))
 def test_random_horizon_conservative_not_stuck(seed):
     _check_horizon_conservative_not_stuck(seed)
+
+
+# ---------------------------------------------------------------------------
+# the idle horizon from the controller's own lookup
+# ---------------------------------------------------------------------------
+
+#: every registered standard whose prerequisite decode ignores the clock
+REUSING = sorted(s for s, (o, t) in DEFAULT_SYSTEMS.items()
+                 if C.lookup_outlives_idle_cycle(compile_spec(s, o, t)))
+DDR5 = ("DDR5", *DEFAULT_SYSTEMS["DDR5"])
+#: (standard triple, controller config, link latency) per case
+IDLE_CASES = dict(
+    {s: ((s, *DEFAULT_SYSTEMS[s]), ControllerConfig(), 0) for s in REUSING},
+    cxl_l40=(DDR5, ControllerConfig(), 40),
+    prac=(DDR5, ControllerConfig(prac_threshold=3), 0),
+    blockhammer=(DDR5, ControllerConfig(blockhammer_threshold=2), 0))
+
+
+def _random_request(cspec, rng):
+    sub = [int(rng.integers(int(cspec.level_counts[j + 1])))
+           for j in range(len(cspec.levels) - 1)]
+    return (bool(rng.random() < 0.3), jnp.asarray(sub, jnp.int32),
+            int(rng.integers(64)))
+
+
+def _idle_horizons(cspec, cfg, link, seed, n_steps=120):
+    """Walk the controller from a random reachable state (a random legal
+    history, a random queue, random later arrivals), jumping to the
+    horizon after each idle cycle as the engine does.  Returns, per
+    idle cycle, ``(idle_horizon from the pass's lookup, channel_horizon
+    recomputed on the post-cycle state)`` and the count of busy
+    cycles."""
+    rng = np.random.default_rng(seed)
+    dut = DeviceUnderTest.from_compiled(cspec)
+    clk = _random_dut_history(dut, rng)
+    dp, state = _mirror_state(cspec, dut.history)
+    cs = C.init_ctrl_state(cspec, 8)._replace(dev=state)
+
+    @jax.jit
+    def step(cs, clk, want, is_write, sub, row):
+        q, ok = C.queue_insert(cs.queue, is_write, jnp.asarray(False), sub,
+                               row, jnp.int32(0), clk, want)
+        out, ev = C.controller_step(cspec, dp, cfg, cs._replace(queue=q),
+                                    clk, link)
+        idle = ~ok & jnp.all(ev.cmd < 0)
+        nxt = clk + 1
+        return (out, idle,
+                C.idle_horizon(cspec, dp, cfg, out, nxt, ev.slot_ready_at,
+                               ev.ref_ready_at, link),
+                C.channel_horizon(cspec, dp, cfg, out, nxt, link))
+
+    pairs, busy = [], 0
+    for i in range(n_steps):
+        want = i < 5 or rng.random() < 0.15
+        is_write, sub, row = _random_request(cspec, rng)
+        cs, idle, h_reuse, h_again = step(cs, jnp.int32(clk),
+                                          jnp.asarray(want),
+                                          jnp.asarray(is_write), sub,
+                                          jnp.int32(row))
+        if bool(idle):
+            pairs.append((int(h_reuse), int(h_again)))
+            clk = max(int(h_reuse), clk + 1)
+        else:
+            busy += 1
+            clk += 1
+    return pairs, busy
+
+
+@pytest.mark.parametrize("case", sorted(IDLE_CASES))
+def test_idle_horizon_from_the_pass_equals_the_recomputed_horizon(case):
+    """After a cycle that accepted and issued nothing, the horizon the
+    engine reduces from the controller pass's lookup equals the horizon
+    recomputed on the post-cycle state, in every standard whose lookup
+    outlives the cycle, behind a CXL link, and with PRAC and
+    BlockHammer."""
+    triple, cfg, link = IDLE_CASES[case]
+    cspec = compile_spec(*triple)
+    for seed in range(2):
+        pairs, busy = _idle_horizons(cspec, cfg, link, seed)
+        assert len(pairs) >= 10 and busy >= 5, (case, seed, busy)
+        assert all(a == b for a, b in pairs), (case, seed, pairs)
+
+
+@pytest.mark.parametrize("standard", ["LPDDR5", "GDDR7"])
+def test_a_data_clock_sync_lookup_does_not_outlive_an_idle_cycle(standard):
+    """Why data-clock-sync standards recompute: their decode reads the
+    clock, so a rank's data clock stopping between the pass's cycle and
+    the next flips a column candidate to its sync command and the pass's
+    lookup no longer holds."""
+    cspec = compile_spec(standard, *DEFAULT_SYSTEMS[standard])
+    assert not C.lookup_outlives_idle_cycle(cspec)
+    differ = 0
+    for seed in range(4):
+        pairs, _ = _idle_horizons(cspec, ControllerConfig(), 0, seed)
+        differ += sum(a != b for a, b in pairs)
+    assert differ > 0
